@@ -20,7 +20,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Optional
 
 from repro.configs.base import ModelConfig
 from repro.core.apply import apply_efficiency_config
@@ -47,6 +47,18 @@ TIERS = {
 TIERS["consumer"] = TIERS["v5e-1"]
 TIERS["datacenter"] = TIERS["v5e-8"]
 TIERS["high_perf"] = TIERS["v5e-256"]
+
+#: jax ``device_kind`` -> the chip family whose peaks (``launch.mesh.HW``)
+#: the tiers above are built from
+TIER_FAMILY = {"TPU v5 lite": "v5e"}
+
+
+def tier_for_devices(devices) -> Optional[HwTier]:
+    """The tier whose peak rates describe ``devices`` (all of one kind),
+    or None when the device kind or the chip count has no tier — callers
+    then report no roofline share rather than borrow another chip's."""
+    family = TIER_FAMILY.get(devices[0].device_kind)
+    return None if family is None else TIERS.get(f"{family}-{len(devices)}")
 
 BYTES = {"bf16": 2.0, "fp8": 1.0, "int8": 1.0, "int4": 0.5}
 

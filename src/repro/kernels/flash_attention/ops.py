@@ -5,11 +5,8 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -23,5 +20,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
         bk = 128 if k.shape[1] % 128 == 0 else k.shape[1]
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       block_q=bq, block_k=bk,
-                                      interpret=not _on_tpu())
+                                      interpret=interpret_mode())
     return attention_ref(q, k, v, causal=causal, window=window)

@@ -44,6 +44,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _int8_dot(a, b):
+    """int8 x int8 -> int32 on the MXU.  Precision is pinned: an ambient
+    ``default_matmul_precision("highest")`` would otherwise ask Mosaic for
+    an fp32-contract integer matmul, which it refuses."""
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.int32)
+
+
 def _int8_mm_kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, acc, *, nk: int):
     ki = pl.program_id(2)
 
@@ -51,16 +59,20 @@ def _int8_mm_kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, acc, *, nk: int):
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    acc[...] += jax.lax.dot(
-        xq_ref[...].astype(jnp.int32), wq_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32)
+    # int8 operands straight into the MXU, int32 accumulate (Mosaic has no
+    # int32 x int32 matmul)
+    acc[...] += _int8_dot(xq_ref[...], wq_ref[...])
 
     @pl.when(ki == nk - 1)
     def _flush():
-        xs = xs_ref[...].astype(jnp.float32)          # (bm,)
-        ws = ws_ref[...].astype(jnp.float32)          # (bn,)
-        o_ref[...] = (acc[...].astype(jnp.float32)
-                      * xs[:, None] * ws[None, :]).astype(o_ref.dtype)
+        o_ref[...] = (acc[...].astype(jnp.float32) * xs_ref[...]
+                      * ws_ref[...]).astype(o_ref.dtype)
+
+
+def _row(v: jax.Array) -> jax.Array:
+    """(N,) per-channel vector as a (1, N) f32 row: 2-D blocks are the ones
+    whose layout Mosaic and XLA agree on."""
+    return v.astype(jnp.float32).reshape(1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -69,7 +81,9 @@ def int8_matmul_pallas(xq, wq, x_scale, w_scale, *, block_m: int = 256,
                        block_n: int = 256, block_k: int = 512,
                        out_dtype=jnp.bfloat16,
                        interpret: bool = False) -> jax.Array:
-    """xq: (M,K) int8; wq: (K,N) int8; x_scale: (M,); w_scale: (N,)."""
+    """xq: (M,K) int8; wq: (K,N) int8; x_scale: (M,); w_scale: (N,).
+    Scales enter as (M,1) / (1,N) columns and rows: a 1-D block's layout
+    does not match the one XLA gives the operand on TPU."""
     m, k = xq.shape
     n = wq.shape[1]
     bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
@@ -82,14 +96,14 @@ def int8_matmul_pallas(xq, wq, x_scale, w_scale, *, block_m: int = 256,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((bk, bn), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((bm,), lambda mi, ni, ki: (mi,)),
-            pl.BlockSpec((bn,), lambda mi, ni, ki: (ni,)),
+            pl.BlockSpec((bm, 1), lambda mi, ni, ki: (mi, 0)),
+            pl.BlockSpec((1, bn), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(xq, wq, x_scale, w_scale)
+    )(xq, wq, x_scale.astype(jnp.float32).reshape(m, 1), _row(w_scale))
 
 
 # ---------------------------------------------------------------------------
@@ -166,19 +180,15 @@ def _w8a8_decode_kernel(x_ref, wq_ref, xs_ref, ws_ref, b_ref, o_ref, acc,
     # scale — elementwise identical to ref.quantize_rowwise, so the int32
     # accumulate (and therefore the output) is bit-identical to the
     # jnp oracle's
-    xs = xs_ref[...].astype(jnp.float32)              # (m,)
-    xq = jnp.clip(jnp.round(x_ref[...].astype(jnp.float32) / xs[:, None]),
+    xs = xs_ref[...]                                  # (m, 1) f32
+    xq = jnp.clip(jnp.round(x_ref[...].astype(jnp.float32) / xs),
                   -127, 127).astype(jnp.int8)
-    acc[...] += jax.lax.dot(
-        xq.astype(jnp.int32), wq_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32)
+    acc[...] += _int8_dot(xq, wq_ref[...])
 
     @pl.when(ki == nk - 1)
     def _flush():
-        ws = ws_ref[...].astype(jnp.float32)          # (bn,)
-        y = acc[...].astype(jnp.float32) * xs[:, None] * ws[None, :]
-        o_ref[...] = (y + b_ref[...].astype(jnp.float32)[None, :]).astype(
-            o_ref.dtype)
+        y = acc[...].astype(jnp.float32) * xs * ws_ref[...]
+        o_ref[...] = (y + b_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -210,15 +220,16 @@ def w8a8_decode_matmul_pallas(x, wq, x_scale, w_scale, bias, *,
         in_specs=[
             pl.BlockSpec((m, bk), lambda ni, ki: (0, ki)),
             pl.BlockSpec((bk, bn), lambda ni, ki: (ki, ni)),
-            pl.BlockSpec((m,), lambda ni, ki: (0,)),
-            pl.BlockSpec((bn,), lambda ni, ki: (ni,)),
-            pl.BlockSpec((bn,), lambda ni, ki: (ni,)),
+            pl.BlockSpec((m, 1), lambda ni, ki: (0, 0)),
+            pl.BlockSpec((1, bn), lambda ni, ki: (0, ni)),
+            pl.BlockSpec((1, bn), lambda ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((m, bn), lambda ni, ki: (0, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.int32)],
         interpret=interpret == "pallas",
-    )(x, wq, x_scale, w_scale, bias)
+    )(x, wq, x_scale.astype(jnp.float32).reshape(m, 1), _row(w_scale),
+      _row(bias))
 
 
 def _fp8_decode_kernel(x_ref, wq_ref, ws_ref, b_ref, o_ref, acc, *, nk: int):
@@ -237,9 +248,7 @@ def _fp8_decode_kernel(x_ref, wq_ref, ws_ref, b_ref, o_ref, acc, *, nk: int):
 
     @pl.when(ki == nk - 1)
     def _flush():
-        ws = ws_ref[...].astype(jnp.float32)          # (bn,)
-        o_ref[...] = (acc[...] * ws[None, :]
-                      + b_ref[...].astype(jnp.float32)[None, :]).astype(
+        o_ref[...] = (acc[...] * ws_ref[...] + b_ref[...]).astype(
             o_ref.dtype)
 
 
@@ -266,11 +275,11 @@ def fp8_decode_matmul_pallas(x, wq, w_scale, bias, *, block_n: int = 256,
         in_specs=[
             pl.BlockSpec((m, bk), lambda ni, ki: (0, ki)),
             pl.BlockSpec((bk, bn), lambda ni, ki: (ki, ni)),
-            pl.BlockSpec((bn,), lambda ni, ki: (ni,)),
-            pl.BlockSpec((bn,), lambda ni, ki: (ni,)),
+            pl.BlockSpec((1, bn), lambda ni, ki: (0, ni)),
+            pl.BlockSpec((1, bn), lambda ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((m, bn), lambda ni, ki: (0, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
         interpret=interpret == "pallas",
-    )(x, wq, w_scale, bias)
+    )(x, wq, _row(w_scale), _row(bias))

@@ -13,12 +13,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.int8_matmul.ref import (
     int4_matmul_ref, int8_matmul_ref, quantize_rowwise)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _block(dim: int, pref: int) -> int:
@@ -50,7 +47,7 @@ def int8_matmul(xq, wq, x_scale, w_scale, *, out_dtype=jnp.bfloat16,
         w_scale = _pad_dim(w_scale, 0, bn, value=1)
         y = int8_matmul_pallas(xq, wq, x_scale, w_scale, block_m=bm,
                                block_n=bn, block_k=bk, out_dtype=out_dtype,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
         return y[:m, :n]
     return int8_matmul_ref(xq, wq, x_scale, w_scale, out_dtype=out_dtype)
 
@@ -88,7 +85,7 @@ def w8a8_matmul_decode(x2, wq, w_scale, *, bias=None,
     b = _pad_dim(b, 0, bn)
     y = w8a8_decode_matmul_pallas(x2, wq, xs, w_scale, b, block_n=bn,
                                   block_k=bk, out_dtype=out_dtype,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
     return y[:, :n]
 
 
@@ -110,7 +107,7 @@ def fp8_matmul_decode(x2, wq, w_scale, *, bias=None,
     b = _pad_dim(b, 0, bn)
     y = fp8_decode_matmul_pallas(x2, wq, w_scale, b, block_n=bn, block_k=bk,
                                  out_dtype=out_dtype,
-                                 interpret=not _on_tpu())
+                                 interpret=interpret_mode())
     return y[:, :n]
 
 

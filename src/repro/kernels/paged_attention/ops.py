@@ -3,8 +3,9 @@ dispatch, and the mesh-sharded (tensor-parallel) wrappers.
 
 bf16/fp32 pools run the plain kernels; int8/fp8 pools (with their
 per-page-per-kv-head scales from ``repro.kvcache``) run the fused-dequant
-variants.  Off-TPU the kernels run in interpret mode, so the engine tests
-cover the exact artifact that runs on TPU.
+variants.  On TPU the kernels compile; on CPU they run in interpret mode,
+so the engine tests cover the same kernel bodies; any other backend is
+refused (``repro.kernels.interpret_mode``).
 
 ``paged_prefix_extend_attention`` is the ONE multi-query entry point:
 speculative verify (W = draft_k + 1, prefix = committed lengths) and
@@ -33,13 +34,11 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels import interpret_mode
 from repro.kernels.paged_attention.ref import (paged_attention_ref,
                                                paged_prefix_extend_ref)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _model_size(mesh, axis: str) -> int:
@@ -65,7 +64,7 @@ def _prefix_extend_local(q, k_pages, v_pages, block_table, prefix_lens,
             paged_prefix_extend_pallas)
         return paged_prefix_extend_pallas(
             q, k_pages, v_pages, block_table, prefix_lens, chunk_k, chunk_v,
-            widths, k_scales, v_scales, interpret=not _on_tpu())
+            widths, k_scales, v_scales, interpret=interpret_mode())
     return paged_prefix_extend_ref(q, k_pages, v_pages, block_table,
                                    prefix_lens, chunk_k, chunk_v, widths,
                                    k_scales, v_scales)
@@ -91,14 +90,12 @@ def paged_prefix_extend_attention(q, k_pages, v_pages, block_table,
         return _prefix_extend_local(q, k_pages, v_pages, block_table,
                                     prefix_lens, chunk_k, chunk_v, widths,
                                     k_scales, v_scales, use_kernel)
-    from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    hs = _shard_axis(tp_impl, m, q.shape[2], k_pages.shape[2], axis)
+    hs = _shard_axis(tp_impl, m, q.shape[2], k_pages.shape[1], axis)
     args = [q, k_pages, v_pages, block_table, prefix_lens,
             chunk_k, chunk_v, widths]
     specs = [P(None, None, hs, None),          # q        (S,W,H,D)
-             P(None, None, hs, None),          # k_pages  (N,page,KH,D)
-             P(None, None, hs, None),          # v_pages
+             P(None, hs, None, None),          # k_pages  (N,KH,page,D)
+             P(None, hs, None, None),          # v_pages
              P(None, None),                    # block_table (replicated)
              P(None),                          # prefix_lens (replicated)
              P(None, None, hs, None),          # chunk_k  (S,W,KH,D)
@@ -115,8 +112,8 @@ def paged_prefix_extend_attention(q, k_pages, v_pages, block_table,
         return _prefix_extend_local(xs[0], xs[1], xs[2], xs[3], xs[4],
                                     xs[5], xs[6], xs[7], ks, vs, use_kernel)
 
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=P(None, None, hs, None), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=P(None, None, hs, None), check_vma=False)
     return fn(*args)
 
 
@@ -127,7 +124,7 @@ def _paged_attention_local(q, k_pages, v_pages, block_table, lengths,
             paged_attention_pallas)
         return paged_attention_pallas(q, k_pages, v_pages, block_table,
                                       lengths, k_scales, v_scales,
-                                      interpret=not _on_tpu())
+                                      interpret=interpret_mode())
     return paged_attention_ref(q, k_pages, v_pages, block_table, lengths,
                                k_scales, v_scales)
 
@@ -138,7 +135,7 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
                     use_kernel: bool = True,
                     mesh=None, axis: str = "model",
                     tp_impl: str = "kv_shard") -> jax.Array:
-    """q: (S,H,D); k_pages/v_pages: (N,page,KH,D); block_table: (S,P);
+    """q: (S,H,D); k_pages/v_pages: (N,KH,page,D); block_table: (S,P);
     lengths: (S,); k_scales/v_scales: (N,KH) fp32 for quantized pools
     -> (S,H,D).  ``mesh``/``tp_impl``: see the module docstring."""
     m = _model_size(mesh, axis)
@@ -146,13 +143,11 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
         return _paged_attention_local(q, k_pages, v_pages, block_table,
                                       lengths, k_scales, v_scales,
                                       use_kernel)
-    from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    hs = _shard_axis(tp_impl, m, q.shape[1], k_pages.shape[2], axis)
+    hs = _shard_axis(tp_impl, m, q.shape[1], k_pages.shape[1], axis)
     args = [q, k_pages, v_pages, block_table, lengths]
     specs = [P(None, hs, None),                # q       (S,H,D)
-             P(None, None, hs, None),          # k_pages (N,page,KH,D)
-             P(None, None, hs, None),          # v_pages
+             P(None, hs, None, None),          # k_pages (N,KH,page,D)
+             P(None, hs, None, None),          # v_pages
              P(None, None),                    # block_table (replicated)
              P(None)]                          # lengths (replicated)
     if k_scales is not None:
@@ -166,6 +161,59 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
         return _paged_attention_local(xs[0], xs[1], xs[2], xs[3], xs[4],
                                       ks, vs, use_kernel)
 
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=P(None, hs, None), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=P(None, hs, None), check_vma=False)
     return fn(*args)
+
+
+def check_paged_kernels(cache, heads: int, q_dtype, *, decode: bool = True,
+                        widths=()) -> None:
+    """Compile the paged kernels at the shapes of ``cache``'s pools — the
+    decode kernel when ``decode``, the prefix-extend kernel at each width
+    in ``widths`` — so that a page size or pool the TPU compiler refuses
+    (VMEM for a page × chunk tile, SMEM for the scales of a huge pool)
+    fails at engine construction with the pool named, not inside the
+    first serving program's compile.  Shapes are the unsharded ones, a
+    superset of what any kv-head shard runs.  No-op in interpret mode."""
+    if interpret_mode():
+        return
+    import jax.numpy as jnp
+    from repro.kernels.paged_attention.paged_attention import (
+        paged_attention_pallas, paged_prefix_extend_pallas)
+    node = _first_paged_node(cache)
+    sds = jax.ShapeDtypeStruct
+    kp = node["k_pages"]
+    n, kh, page, d = kp.shape[-4:]
+    s_n, p_n = node["block_table"].shape[-2:]
+    pool = sds((n, kh, page, d), kp.dtype)
+    scales = (sds((n, kh), jnp.float32),) * 2 if "k_scales" in node else ()
+    i32 = jnp.int32
+    try:
+        if decode:
+            paged_attention_pallas.lower(
+                sds((s_n, heads, d), q_dtype), pool, pool,
+                sds((s_n, p_n), i32), sds((s_n,), i32), *scales).compile()
+        for w in widths:
+            chunk = sds((1, w, kh, d), q_dtype)
+            paged_prefix_extend_pallas.lower(
+                sds((1, w, heads, d), q_dtype), pool, pool,
+                sds((1, p_n), i32), sds((1,), i32), chunk, chunk,
+                sds((1,), i32), *scales).compile()
+    except jax.errors.JaxRuntimeError as e:
+        raise ValueError(
+            f"the TPU compiler refuses the paged-attention kernels for "
+            f"page_size={page} over {n} pages of {jnp.dtype(kp.dtype).name} "
+            f"({kh} kv heads x head_dim {d}, chunk widths {tuple(widths)}): "
+            f"{str(e).splitlines()[0]}") from e
+
+
+def _first_paged_node(tree) -> Optional[dict]:
+    """The first ``{k_pages, v_pages, ...}`` node of a model cache tree."""
+    if isinstance(tree, dict):
+        if "k_pages" in tree:
+            return tree
+        for sub in tree.values():
+            node = _first_paged_node(sub)
+            if node is not None:
+                return node
+    return None

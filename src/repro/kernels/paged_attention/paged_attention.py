@@ -10,9 +10,11 @@ Pages are STREAMED, never gathered: the block table and per-slot lengths
 ride in as scalar-prefetch operands (``PrefetchScalarGridSpec``), and the
 K/V BlockSpec index maps look the physical page id up as
 ``block_table[slot, page_block]`` — each grid step DMAs exactly one
-(page_size, head_dim) tile from HBM.  This is what replaces the
-``jnp.take`` of serve/paged.py, which materialized a contiguous
-(max_pages · page_size) copy of the whole context per decode step.
+(page_size, head_dim) tile from HBM.  Pools are HEAD-MAJOR,
+(N, KH, page, D): the tile is then the pool's own last two dims, the
+only (page, D) block the TPU compiler accepts for every page size and
+dtype (a page-major (N, page, KH, D) pool would need a (page, 1, D)
+block, whose second-minor dim of 1 Mosaic refuses).
 
 GQA is handled like kernels/flash_attention: the kv-head grid axis selects
 one stored head, the q block carries that head's ``group`` query heads, and
@@ -22,8 +24,10 @@ compute); partially-filled last pages are masked via a broadcasted iota
 against the slot's length.  fp32 accumulation throughout.
 
 Quantized pools (int8 / fp8-e4m3, ``repro.kvcache``): the per-page-per-
-kv-head fp32 amax scales ride in as two extra scalar-prefetch operands
-(SMEM-resident, (N, KH)), and dequant is FUSED into the online-softmax
+kv-head fp32 amax scales ride in as two extra scalar-prefetch operands,
+flattened to 1-D (N·KH,) — a 2-D (N, KH) SMEM array pads every row to
+512 B and a pool of a few thousand pages overflows the 1 MiB SMEM — and
+dequant is FUSED into the online-softmax
 inner loop — the K scale folds into the score scale (``(q·k_q)·s·k_s``)
 and the V scale folds into the p·v accumulation (``(p·v_q)·v_s``), so no
 dequantized page is ever materialized in HBM or VMEM.  Streaming int8
@@ -46,10 +50,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Scoped VMEM for the prefix-extend kernel.  Its chunk step holds a
+# (W·G, W) f32 score tile beside (W·G, D) q/out/accumulator tiles, and
+# XLA may place the kernel's output in VMEM too: a 512-token chunk with
+# G = 6 and f32 queries inside a sharded serving program needed 20.8 MB,
+# past the compiler's 16 MiB default.  A v5e core has 128 MiB of VMEM.
+PREFIX_EXTEND_VMEM_BYTES = 48 * 2 ** 20
 
 
 def _paged_kernel(*refs, scale: float, page_size: int, n_page_blocks: int,
-                  quantized: bool):
+                  n_kv_heads: int, quantized: bool):
     if quantized:
         (bt_ref, len_ref, ks_ref, vs_ref,
          q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr) = refs
@@ -71,13 +81,13 @@ def _paged_kernel(*refs, scale: float, page_size: int, n_page_blocks: int,
 
     @pl.when(page_start < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                   # (G, D)
+        k = k_ref[...].astype(jnp.float32)                   # (page, D)
+        v = v_ref[...].astype(jnp.float32)
         if quantized:
-            page_id = bt_ref[s_i, p_i]
-            k_s = ks_ref[page_id, k_i]                       # fp32 scalars
-            v_s = vs_ref[page_id, k_i]
+            flat = bt_ref[s_i, p_i] * n_kv_heads + k_i
+            k_s = ks_ref[flat]                               # fp32 scalars
+            v_s = vs_ref[flat]
             sc = scale * k_s                                 # fused K dequant
         else:
             v_s = None
@@ -105,11 +115,11 @@ def _paged_kernel(*refs, scale: float, page_size: int, n_page_blocks: int,
         # length-0 slots (free engine slots) never ran _body: l is 0 and
         # the flush writes zeros, matching ref.py's masked softmax.
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _prefix_extend_kernel(*refs, scale: float, page_size: int,
-                          n_page_blocks: int, group: int, width: int,
+                          n_page_blocks: int, n_kv_heads: int, group: int,
                           quantized: bool):
     """Width-parameterized prefix-extend attention: W query positions per
     slot against the slot's paged prefix plus a fresh causal chunk.  Grid
@@ -167,13 +177,13 @@ def _prefix_extend_kernel(*refs, scale: float, page_size: int,
 
     @pl.when((p_i < n_page_blocks) & (p_i * page_size < length))
     def _prefix_body():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (W·G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                   # (W·G, D)
+        k = k_ref[...].astype(jnp.float32)                   # (page, D)
+        v = v_ref[...].astype(jnp.float32)
         if quantized:
-            page_id = bt_ref[s_i, p_i]
-            k_s = ks_ref[page_id, k_i]
-            v_s = vs_ref[page_id, k_i]
+            flat = bt_ref[s_i, p_i] * n_kv_heads + k_i
+            k_s = ks_ref[flat]
+            v_s = vs_ref[flat]
             sc = scale * k_s
         else:
             v_s = None
@@ -187,9 +197,9 @@ def _prefix_extend_kernel(*refs, scale: float, page_size: int,
 
     @pl.when((p_i == n_page_blocks) & (wid > 0))
     def _chunk_body():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (W·G, D)
-        ck = ck_ref[0, :, 0, :].astype(jnp.float32)          # (W, D)
-        cv = cv_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                   # (W·G, D)
+        ck = ck_ref[...].astype(jnp.float32)                 # (W, D)
+        cv = cv_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, ck, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         w_of_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
@@ -202,7 +212,45 @@ def _prefix_extend_kernel(*refs, scale: float, page_size: int,
         # width-0 slots never ran a body: l stays 0 and the flush writes
         # zeros, matching ref.py's masked softmax
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _tile_spec(rows: int, d: int) -> pl.BlockSpec:
+    """(rows, D) tile of a (S, KH, rows, D) operand at grid (slot, kv head)."""
+    return pl.BlockSpec((None, None, rows, d),
+                        lambda s, k, p, *_: (s, k, 0, 0))
+
+
+def _page_spec(page: int, d: int, p_n: int) -> pl.BlockSpec:
+    """One (page, D) tile of a head-major (N, KH, page, D) pool: physical
+    page ``block_table[slot, page_block]``.  Index maps see every
+    scalar-prefetch operand after the grid coordinates; only the block
+    table is consulted.  The prefix-extend grid runs one step past the
+    table (its chunk step), hence the clamp."""
+    return pl.BlockSpec(
+        (None, None, page, d),
+        lambda s, k, p, bt, *_: (bt[s, jnp.minimum(p, p_n - 1)], k, 0, 0))
+
+
+def _prefetch(block_table, per_slot, k_scales, v_scales):
+    """Scalar-prefetch operands: the block table, the per-slot int32
+    vectors, and for quantized pools the (N, KH) scales flattened to
+    (N·KH,) (1-D SMEM does not pad rows)."""
+    ops = [block_table.astype(jnp.int32)] + [x.astype(jnp.int32)
+                                             for x in per_slot]
+    if k_scales is not None:
+        ops += [k_scales.astype(jnp.float32).reshape(-1),
+                v_scales.astype(jnp.float32).reshape(-1)]
+    return ops
+
+
+def _check_pools(q_heads, k_pages, k_scales):
+    _, kh, page, _ = k_pages.shape
+    assert q_heads % kh == 0, (q_heads, kh)
+    quantized = k_scales is not None
+    assert quantized == (k_pages.dtype not in (jnp.bfloat16, jnp.float32)), \
+        (k_pages.dtype, quantized)
+    return kh, page, quantized
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -217,11 +265,7 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
     at W = k+1 (prefix = committed lengths), chunked prefill at W =
     chunk width (prefix = the chunk's page-aligned start)."""
     s_n, w_n, h, d = q.shape
-    _, page, kh, _ = k_pages.shape
-    assert h % kh == 0, (h, kh)
-    quantized = k_scales is not None
-    assert quantized == (k_pages.dtype not in (jnp.bfloat16, jnp.float32)), \
-        (k_pages.dtype, quantized)
+    kh, page, quantized = _check_pools(h, k_pages, k_scales)
     g = h // kh
     p_n = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
@@ -229,26 +273,18 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
     # w = r // G, query head r % G
     q4 = q.reshape(s_n, w_n, kh, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(s_n, kh, w_n * g, d)
-
-    q_spec = pl.BlockSpec((1, 1, w_n * g, d),
-                          lambda s, k, p, bt, *_: (s, k, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (1, page, 1, d),
-        lambda s, k, p, bt, *_: (bt[s, jnp.minimum(p, p_n - 1)], 0, k, 0))
-    chunk_spec = pl.BlockSpec((1, w_n, 1, d),
-                              lambda s, k, p, bt, *_: (s, 0, k, 0))
-    o_spec = pl.BlockSpec((1, 1, w_n * g, d),
-                          lambda s, k, p, bt, *_: (s, k, 0, 0))
-    prefetch = [block_table.astype(jnp.int32),
-                prefix_lens.astype(jnp.int32), widths.astype(jnp.int32)]
-    if quantized:
-        prefetch += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+    # chunk K/V head-major like the pools: a (W, D) tile per kv head
+    ck = chunk_k.transpose(0, 2, 1, 3)
+    cv = chunk_v.transpose(0, 2, 1, 3)
+    prefetch = _prefetch(block_table, (prefix_lens, widths),
+                         k_scales, v_scales)
+    kv_spec = _page_spec(page, d, p_n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(s_n, kh, p_n + 1),
-        in_specs=[q_spec, kv_spec, kv_spec, chunk_spec, chunk_spec],
-        out_specs=o_spec,
+        in_specs=[_tile_spec(w_n * g, d), kv_spec, kv_spec,
+                  _tile_spec(w_n, d), _tile_spec(w_n, d)],
+        out_specs=_tile_spec(w_n * g, d),
         scratch_shapes=[
             pltpu.VMEM((w_n * g, 1), jnp.float32),
             pltpu.VMEM((w_n * g, 1), jnp.float32),
@@ -256,12 +292,14 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
         ])
     out = pl.pallas_call(
         functools.partial(_prefix_extend_kernel, scale=scale, page_size=page,
-                          n_page_blocks=p_n, group=g, width=w_n,
+                          n_page_blocks=p_n, n_kv_heads=kh, group=g,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, kh, w_n * g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PREFIX_EXTEND_VMEM_BYTES),
         interpret=interpret,
-    )(*prefetch, q4, k_pages, v_pages, chunk_k, chunk_v)
+    )(*prefetch, q4, k_pages, v_pages, ck, cv)
     return out.reshape(s_n, kh, w_n, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(s_n, w_n, h, d)
 
@@ -270,35 +308,21 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
 def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths,
                            k_scales=None, v_scales=None, *,
                            interpret: bool = False) -> jax.Array:
-    """q: (S,H,D); k_pages/v_pages: (N,page,KH,D); block_table: (S,P) int32;
+    """q: (S,H,D); k_pages/v_pages: (N,KH,page,D); block_table: (S,P) int32;
     lengths: (S,) int32 -> (S,H,D).  Quantized pools additionally take
     k_scales/v_scales: (N,KH) fp32 per-page-per-kv-head amax scales."""
     s_n, h, d = q.shape
-    _, page, kh, _ = k_pages.shape
-    assert h % kh == 0, (h, kh)
-    quantized = k_scales is not None
-    assert quantized == (k_pages.dtype not in (jnp.bfloat16, jnp.float32)), \
-        (k_pages.dtype, quantized)
+    kh, page, quantized = _check_pools(h, k_pages, k_scales)
     g = h // kh
     p_n = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
-    q4 = q.reshape(s_n, kh, g, d)
-
-    # index maps see every scalar-prefetch operand appended after the grid
-    # coordinates; only the block table is consulted
-    q_spec = pl.BlockSpec((1, 1, g, d), lambda s, k, p, bt, *_: (s, k, 0, 0))
-    kv_spec = pl.BlockSpec((1, page, 1, d),
-                           lambda s, k, p, bt, *_: (bt[s, p], 0, k, 0))
-    o_spec = pl.BlockSpec((1, 1, g, d), lambda s, k, p, bt, *_: (s, k, 0, 0))
-    prefetch = [block_table.astype(jnp.int32), lengths.astype(jnp.int32)]
-    if quantized:
-        prefetch += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+    prefetch = _prefetch(block_table, (lengths,), k_scales, v_scales)
+    kv_spec = _page_spec(page, d, p_n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(s_n, kh, p_n),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
+        in_specs=[_tile_spec(g, d), kv_spec, kv_spec],
+        out_specs=_tile_spec(g, d),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -306,9 +330,10 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths,
         ])
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, page_size=page,
-                          n_page_blocks=p_n, quantized=quantized),
+                          n_page_blocks=p_n, n_kv_heads=kh,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, kh, g, d), q.dtype),
         interpret=interpret,
-    )(*prefetch, q4, k_pages, v_pages)
+    )(*prefetch, q.reshape(s_n, kh, g, d), k_pages, v_pages)
     return out.reshape(s_n, h, d)

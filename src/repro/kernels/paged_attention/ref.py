@@ -25,6 +25,21 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def _gather_context(k_pages, v_pages, block_table, k_scales, v_scales):
+    """Every slot's pages, dequantized, as contiguous fp32 (S, P·page, KH, D)
+    context — head-major pools (N, KH, page, D) turned position-major."""
+    s_n, p_n = block_table.shape
+    _, kh, page, d = k_pages.shape
+
+    def one(pages, scales):
+        x = pages[block_table].astype(jnp.float32)       # (S,P,KH,page,D)
+        if scales is not None:
+            x = x * scales[block_table][..., None, None]
+        return x.transpose(0, 1, 3, 2, 4).reshape(s_n, p_n * page, kh, d)
+
+    return one(k_pages, k_scales), one(v_pages, v_scales)
+
+
 def paged_prefix_extend_ref(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, block_table: jax.Array,
                             prefix_lens: jax.Array, chunk_k: jax.Array,
@@ -51,17 +66,11 @@ def paged_prefix_extend_ref(q: jax.Array, k_pages: jax.Array,
     """
     lengths = prefix_lens
     s_n, w_n, h, d = q.shape
-    _, page, kh, _ = k_pages.shape
+    _, kh, page, _ = k_pages.shape
     p_n = block_table.shape[1]
     g = h // kh
-    k = k_pages[block_table].astype(jnp.float32)         # (S,P,page,KH,D)
-    v = v_pages[block_table].astype(jnp.float32)
-    if k_scales is not None:
-        k = k * k_scales[block_table][:, :, None, :, None]
-        v = v * v_scales[block_table][:, :, None, :, None]
+    k, v = _gather_context(k_pages, v_pages, block_table, k_scales, v_scales)
     t = p_n * page
-    k = k.reshape(s_n, t, kh, d)
-    v = v.reshape(s_n, t, kh, d)
     qg = q.reshape(s_n, w_n, kh, g, d).astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     s_ctx = jnp.einsum("swkgd,stkd->skgwt", qg, k) * scale
@@ -92,20 +101,14 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         block_table: jax.Array, lengths: jax.Array,
                         k_scales: Optional[jax.Array] = None,
                         v_scales: Optional[jax.Array] = None) -> jax.Array:
-    """q: (S,H,D); k_pages/v_pages: (N,page,KH,D); block_table: (S,P) int32;
+    """q: (S,H,D); k_pages/v_pages: (N,KH,page,D); block_table: (S,P) int32;
     lengths: (S,) int32 — keys at kpos < lengths[s] are live;
     k_scales/v_scales: (N,KH) fp32 for quantized pools -> (S,H,D)."""
     s_n, h, d = q.shape
-    _, page, kh, _ = k_pages.shape
+    _, kh, page, _ = k_pages.shape
     p_n = block_table.shape[1]
     g = h // kh
-    k = k_pages[block_table].astype(jnp.float32)         # (S,P,page,KH,D)
-    v = v_pages[block_table].astype(jnp.float32)
-    if k_scales is not None:
-        k = k * k_scales[block_table][:, :, None, :, None]
-        v = v * v_scales[block_table][:, :, None, :, None]
-    k = k.reshape(s_n, p_n * page, kh, d)                # (S,T,KH,D)
-    v = v.reshape(s_n, p_n * page, kh, d)
+    k, v = _gather_context(k_pages, v_pages, block_table, k_scales, v_scales)
     qg = q.reshape(s_n, kh, g, d)
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     scores = jnp.einsum("skgd,stkd->skgt", qg.astype(jnp.float32),

@@ -3,11 +3,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-5,
@@ -19,5 +16,5 @@ def rmsnorm(x, scale, *, eps: float = 1e-5,
             rows *= s
         br = 256 if rows % 256 == 0 else rows
         return rmsnorm_pallas(x, scale, eps=eps, block_rows=br,
-                              interpret=not _on_tpu())
+                              interpret=interpret_mode())
     return rmsnorm_ref(x, scale, eps)

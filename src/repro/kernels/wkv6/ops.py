@@ -11,11 +11,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.wkv6.ref import wkv6_chunked_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def wkv6(r, k, v, logw, u, s0, *, use_kernel: bool = False,
@@ -24,7 +21,7 @@ def wkv6(r, k, v, logw, u, s0, *, use_kernel: bool = False,
     if use_kernel:
         from repro.kernels.wkv6.wkv6 import wkv6_pallas
         return wkv6_pallas(r, k, v, logw, u, s0, chunk=chunk,
-                           interpret=not _on_tpu())
+                           interpret=interpret_mode())
     o, s = wkv6_chunked_ref(r.astype(jnp.float32), k.astype(jnp.float32),
                             v.astype(jnp.float32), logw.astype(jnp.float32),
                             u.astype(jnp.float32), s0.astype(jnp.float32),

@@ -8,7 +8,9 @@ serve/paged.py stop carrying their own copies.
 Contiguous node:  {"k": (B,S,KH,D), "v": (B,S,KH,D)
                    [, "k_scale": (B,S,KH) f32, "v_scale": (B,S,KH) f32]}
 MLA node:         {"c_kv": (B,S,dc), "k_pe": (B,S,rr)}          (bf16)
-Paged node:       {"k_pages"/"v_pages": (N,page,KH,D),
+Paged node:       {"k_pages"/"v_pages": (N,KH,page,D)  (head-major: a
+                   page's (page, D) tile per kv head is what the paged
+                   kernels stream; kernels/paged_attention),
                    [, "k_scales"/"v_scales": (N,KH) f32]
                    "block_table": (n_slots, pages_per_slot) int32}
 
@@ -64,9 +66,9 @@ def alloc_paged(spec: CacheSpec, a: AttentionConfig, n_slots: int,
     kvh = spec.stored_kv_heads(a)
     page = spec.page_size
     c = {
-        "k_pages": jnp.zeros((n_pages, page, kvh, a.head_dim),
+        "k_pages": jnp.zeros((n_pages, kvh, page, a.head_dim),
                              spec.store_dtype),
-        "v_pages": jnp.zeros((n_pages, page, kvh, a.head_dim),
+        "v_pages": jnp.zeros((n_pages, kvh, page, a.head_dim),
                              spec.store_dtype),
         "block_table": jnp.zeros((n_slots, pages_per_slot), jnp.int32),
     }
@@ -134,7 +136,7 @@ def kv_views(cache: dict):
 
 
 def constrain_paged_pools(cache: dict) -> dict:
-    """Pin paged pools to their serving sharding: pages (…,page,KH,D)
+    """Pin paged pools to their serving sharding: pages (…,KH,page,D)
     kv-head-sharded over "model", scale tensors (…,KH) likewise, block
     table replicated.  Called after every paged write so the pools carried
     through the decode scan / chunk loop never drift to replicated (a
@@ -146,7 +148,7 @@ def constrain_paged_pools(cache: dict) -> dict:
     for name in ("k_pages", "v_pages"):
         if name in out:
             x = out[name]
-            axes = (None,) * (x.ndim - 2) + ("model", None)
+            axes = (None,) * (x.ndim - 3) + ("model", None, None)
             out[name] = maybe_constrain(x, *axes)
     for name in ("k_scales", "v_scales"):
         if name in out:
@@ -167,7 +169,7 @@ def paged_views(cache: dict):
 def _quant_token_write(pages, scales, pidx, off, new):
     """Append one quantized token per slot at (pidx, off), growing the
     page's running amax scale and requantizing the page when it grows.
-    pages: (N,page,KH,D); scales: (N,KH); new: (S,KH,D) bf16.
+    pages: (N,KH,page,D); scales: (N,KH); new: (S,KH,D) bf16.
 
     A write at offset 0 RESETS the page's scale instead of growing it: a
     page's first token is always written at offset 0 (allocations, lazy
@@ -196,15 +198,15 @@ def _quant_token_write(pages, scales, pidx, off, new):
     grew = jnp.any((ns > old) & (old > 0) & ~fresh & (pidx != 0)[:, None])
 
     def rescale_pages(pages):
-        pg = pages[pidx]                                         # (S,page,KH,D)
-        pg = requantize(pg, old[:, None], ns[:, None], axis=-1)
-        pg = pg.at[jnp.arange(s_n), off].set(tok)
+        pg = pages[pidx]                                         # (S,KH,page,D)
+        pg = requantize(pg, old[:, :, None], ns[:, :, None], axis=-1)
+        pg = pg.at[jnp.arange(s_n), :, off].set(tok)
         # duplicate pidx entries only ever alias the null page (free
         # slots); whichever garbage write wins there is masked away
         return pages.at[pidx].set(pg)
 
     def append_only(pages):
-        return pages.at[pidx, off].set(tok)
+        return pages.at[pidx, :, off].set(tok)
 
     pages = jax.lax.cond(grew, rescale_pages, append_only, pages)
     return pages, scales.at[pidx].set(ns)
@@ -221,7 +223,7 @@ def paged_write_batch(cache: dict, positions: jax.Array,
     rejected drafts never touch a live page, so rollback is exact even
     for quantized pools whose scales a rejected tail could have grown)."""
     kp, vp, ks, vs, bt = paged_views(cache)
-    page = kp.shape[1]
+    page = kp.shape[2]
     s_n = positions.shape[0]
     lpage = jnp.minimum(positions // page, bt.shape[1] - 1)      # pad-safe
     pidx = bt[jnp.arange(s_n), lpage]                            # (S,)
@@ -231,8 +233,8 @@ def paged_write_batch(cache: dict, positions: jax.Array,
         off = jnp.where(mask, off, 0)
     out = dict(cache)
     if ks is None:
-        out["k_pages"] = kp.at[pidx, off].set(k_new.astype(kp.dtype))
-        out["v_pages"] = vp.at[pidx, off].set(v_new.astype(vp.dtype))
+        out["k_pages"] = kp.at[pidx, :, off].set(k_new.astype(kp.dtype))
+        out["v_pages"] = vp.at[pidx, :, off].set(v_new.astype(vp.dtype))
         return out
     out["k_pages"], out["k_scales"] = _quant_token_write(kp, ks, pidx, off,
                                                          k_new)
@@ -252,7 +254,7 @@ def _quant_scatter(pages, scales, pidx, off, rows, amax):
     scales = scales.at[pidx].max(amax / qmax)
     per_tok = scales[pidx]                                       # (B,T,KH)
     q = quantize_with_scale(rows, per_tok, pages.dtype, axis=-1)
-    return pages.at[pidx, off].set(q), scales
+    return pages.at[pidx, :, off].set(q), scales
 
 
 def paged_scatter_prefill(cache: dict, slot_ids: jax.Array,
@@ -275,7 +277,7 @@ def paged_scatter_prefill(cache: dict, slot_ids: jax.Array,
     """
     kp, vp, ks, vs, bt = paged_views(cache)
     b, t = k_rows.shape[:2]
-    page = kp.shape[1]
+    page = kp.shape[2]
     tpos = jnp.arange(t)[None, :]                                # (1,T)
     if starts is None:
         starts = jnp.zeros((b,), jnp.int32)
@@ -287,8 +289,8 @@ def paged_scatter_prefill(cache: dict, slot_ids: jax.Array,
     off = jnp.where(valid, apos % page, 0)
     out = dict(cache)
     if ks is None:
-        out["k_pages"] = kp.at[pidx, off].set(k_rows.astype(kp.dtype))
-        out["v_pages"] = vp.at[pidx, off].set(v_rows.astype(vp.dtype))
+        out["k_pages"] = kp.at[pidx, :, off].set(k_rows.astype(kp.dtype))
+        out["v_pages"] = vp.at[pidx, :, off].set(v_rows.astype(vp.dtype))
         return out
     vm = valid[..., None].astype(jnp.float32)                    # (B,T,1)
     k_amax = jnp.max(jnp.abs(k_rows.astype(jnp.float32)), axis=-1) * vm

@@ -14,6 +14,7 @@ import os
 import re
 
 import jax
+from jax.sharding import AxisType
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
@@ -51,7 +52,7 @@ def ensure_host_devices(n: int) -> bool:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int = 1):
@@ -65,7 +66,10 @@ def make_host_mesh(*, model: int = 1):
             f"On CPU, force more host devices BEFORE jax initializes: "
             f"XLA_FLAGS={_FORCE_FLAG}=N or "
             f"repro.launch.mesh.ensure_host_devices(N).")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    # Auto axes: model code places arrays with with_sharding_constraint,
+    # which refuses Explicit axes (make_mesh's default since JAX 0.7)
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants (per chip) — used by roofline + cost model.

@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import LM
 from repro.serve.engine import Engine
 
@@ -180,6 +181,7 @@ def main(argv=None):
                          "--calibration-out artifact (corrections keep "
                          "updating online from this drive's samples)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.kvcache import normalize_dtype
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -343,12 +345,14 @@ def _write_artifacts(args, cfg, eng, mesh, tracer, profiler):
     telemetry on disk."""
     calib = None
     if profiler.enabled:
-        from repro.core.costmodel import TIERS, CalibratedCostModel
+        from repro.core.costmodel import CalibratedCostModel, tier_for_devices
         calib = (CalibratedCostModel.load(args.calibration_in)
                  if args.calibration_in else CalibratedCostModel())
         records = calib.fit_profile(profiler, eng.lm.cfg)
         calib.register_metrics(eng.metrics)
-        profiler.export_gauges(eng.metrics, TIERS["v5e-1"])
+        devices = (jax.devices()[:1] if mesh is None
+                   else list(mesh.devices.flat))
+        profiler.export_gauges(eng.metrics, tier_for_devices(devices))
         print(f"[serve] profiled {len(records)} dispatches across "
               f"{len(calib.factors)} (kind × arm) calibration series")
     if args.calibration_out and calib is not None:

@@ -113,7 +113,7 @@ def cache_shardings(cache_abs: Any, mesh: Mesh, cfg: ModelConfig,
             # paged KV pools (decode_attn_impl="paged_pallas"): pages have
             # no batch dim (slots share the pool), so never batch-shard;
             # TP splits the stored kv-head dim over "model".
-            h_dim = l.ndim - 2
+            h_dim = l.ndim - 3                # (…, KH, page, D)
             if model > 1 and l.shape[h_dim] % model == 0:
                 dims[h_dim] = "model"
             return _ns(mesh, *dims)

@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.core.apply import apply_efficiency_config, apply_to_params
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import LM
 from repro.optim.adamw import cosine_schedule
@@ -49,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.with_(max_seq_len=max(cfg.max_seq_len, args.seq_len))

@@ -410,7 +410,7 @@ def attention_prefill_paged(p: dict, x: jax.Array, a: AttentionConfig,
     max_pages = rest[0] if rest else None
     b, c, _ = x.shape
     kvh = a.kv_heads_effective()
-    kvh_store = cache["k_pages"].shape[2]
+    kvh_store = cache["k_pages"].shape[1]
 
     apos = starts[:, None] + jnp.arange(c)[None, :]              # (B,c)
     q = linear_apply(p["wq"], x).reshape(b, c, a.heads_padded, a.head_dim)
@@ -488,7 +488,7 @@ def attention_verify_paged(p: dict, x: jax.Array, a: AttentionConfig,
     max_pages = rest[0] if rest else None
     b, w, _ = x.shape
     kvh = a.kv_heads_effective()
-    kvh_store = cache["k_pages"].shape[2]
+    kvh_store = cache["k_pages"].shape[1]
 
     apos = lengths[:, None] + jnp.arange(w)[None, :]             # (S,W)
     q = linear_apply(p["wq"], x).reshape(b, w, a.heads_padded, a.head_dim)
@@ -598,7 +598,7 @@ def attention_decode_paged(p: dict, x: jax.Array, a: AttentionConfig,
         raise NotImplementedError("paged decode: sliding window unsupported")
     b, _, d = x.shape
     kvh = a.kv_heads_effective()
-    kvh_store = cache["k_pages"].shape[2]
+    kvh_store = cache["k_pages"].shape[1]
     pos = _posv(pos, b)
     posv = pos[:, None]
 
@@ -636,7 +636,6 @@ def attention_decode_cp(p: dict, x: jax.Array, a: AttentionConfig,
     pjit emits when the kv-head count doesn't divide the model axis.
     x: (B,1,d); cache k/v: (B,S,KH,D) sharded P(dp, axis, None, None)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.sharding.rules import dp_axes
 
     b, _, d = x.shape
@@ -695,13 +694,13 @@ def attention_decode_cp(p: dict, x: jax.Array, a: AttentionConfig,
             k_l, v_l
 
     cache_spec = P(dp_spec, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(dp_spec, None, None),
                   P(dp_spec, None, None), cache_spec, cache_spec,
                   P(dp_spec)),
         out_specs=(P(dp_spec, None), cache_spec, cache_spec),
-        check_rep=False)
+        check_vma=False)
     o, k_cache, v_cache = fn(q, k_new, v_new, cache["k"], cache["v"], pos)
     y = linear_apply(p["wo"], _mask_pad_heads(o[:, None], a))
     return y, {"k": k_cache, "v": v_cache}

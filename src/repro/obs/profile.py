@@ -118,20 +118,18 @@ class DispatchProfiler:
     def flops_bytes(self, cost_key) -> Optional[Tuple[float, float]]:
         """(FLOPs, HBM bytes) for one dispatch signature, from the
         compiled program's cost_analysis.  Compiles at most once per
-        signature; returns None when XLA reports nothing."""
+        signature; returns None when XLA reports nothing.  A compile
+        failure propagates: it is the same program the engine runs."""
         if cost_key is None:
             return None
         if cost_key in self._cost_cache:
             return self._cost_cache[cost_key]
         from repro.launch.roofline import resolve_cost_analysis
         jitfn, abstract, static_kwargs = self._cost_specs[cost_key]
-        try:
-            compiled = jitfn.lower(*abstract, **static_kwargs).compile()
-            ca = resolve_cost_analysis(compiled)
-            out = (float(ca.get("flops", 0.0)),
-                   float(ca.get("bytes accessed", 0.0)))
-        except Exception:                     # pragma: no cover - backend-dep
-            out = None
+        compiled = jitfn.lower(*abstract, **static_kwargs).compile()
+        ca = resolve_cost_analysis(compiled)
+        out = ((float(ca.get("flops", 0.0)),
+                float(ca.get("bytes accessed", 0.0))) if ca else None)
         self._cost_cache[cost_key] = out
         return out
 

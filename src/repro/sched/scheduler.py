@@ -165,9 +165,12 @@ class SchedEngine(PagedEngine):
         # and a page-starved queue is probed every tick — memoize per
         # request, keyed on the token count (readmits grow it)
         self._chains: Dict[int, tuple] = {}
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._chunk_jit = jax.jit(self._chunk_impl, donate_argnums=donate,
+        self._chunk_jit = jax.jit(self._chunk_impl, donate_argnums=(1,),
                                   static_argnames=("max_pages",))
+        from repro.kernels.paged_attention.ops import check_paged_kernels
+        check_paged_kernels(self.cache, self.lm.cfg.attention.heads_padded,
+                            self.lm.dtype, decode=False,
+                            widths=(prefill_chunk,))
 
     # ------------------------------------------------------------------
     # device programs

@@ -22,7 +22,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _partial_attn(q, k, v, valid, scale):
@@ -71,8 +70,8 @@ def context_parallel_decode(q, k_cache, v_cache, pos, mesh: Mesh, *,
         o_final = o_g / jnp.maximum(l_g, 1e-30)[..., None]
         return o_final.reshape(b, h, hd).astype(q_l.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None)),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     return fn(q, k_cache, v_cache)

@@ -508,10 +508,13 @@ class PagedEngine(_EngineBase):
             "serve_decode_dispatches_total", "fused decode-block dispatches")
 
         # the old cache is dead the moment a dispatch returns — donate it
-        # so the page pools aren't double-resident (no-op on CPU)
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._admit_jit = jax.jit(self._admit_impl, donate_argnums=donate)
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=donate)
+        # so the page pools aren't double-resident.  Donated on every
+        # backend, so the CPU tests catch any host read of a dead cache.
+        self._admit_jit = jax.jit(self._admit_impl, donate_argnums=(1,))
+        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1,))
+        from repro.kernels.paged_attention.ops import check_paged_kernels
+        check_paged_kernels(self.cache, cfg.attention.heads_padded,
+                            self.lm.dtype)
 
     # ------------------------------------------------------------------
     # device programs

@@ -6,8 +6,7 @@ adaptation (DESIGN.md §3): large lane-aligned pages (256-token default), a
 per-slot block table, and a Pallas flash-decoding kernel
 (``kernels/paged_attention``) whose BlockSpec index maps stream pages
 straight from HBM, one (page, head_dim) tile per grid step, for ALL active
-slots in one launch.  The legacy ``paged_attention`` below (one slot,
-``jnp.take`` gather into a contiguous copy) is kept as a readable baseline.
+slots in one launch.
 
 This module owns the HOST side: the free list / block-table accounting and
 the engine-facing cache-tree walkers.  Device-side page arrays, quantized
@@ -276,7 +275,7 @@ def scatter_prefill_cache(paged_cache, contig_cache, slot_ids, lengths,
         if "k_scale" in contig_cache:
             k_rows = dequantize(k_rows, contig_cache["k_scale"])
             v_rows = dequantize(v_rows, contig_cache["v_scale"])
-        if paged_cache["k_pages"].ndim == 5:   # (G, N, page, KH, D) stacked
+        if paged_cache["k_pages"].ndim == 5:   # (G, N, KH, page, D) stacked
             out = jax.vmap(paged_scatter_prefill,
                            in_axes=(0, None, None, 0, 0, None))(
                 paged_cache, slot_ids, lengths, k_rows, v_rows, starts)
@@ -321,7 +320,7 @@ def commit_spec_cache(paged_cache, stage_cache, lengths, n_write):
             node, _ = jax.lax.scan(body, node, jnp.arange(w))
             return constrain_paged_pools(node)
 
-        if paged_cache["k_pages"].ndim == 5:   # (G, N, page, KH, D) stacked
+        if paged_cache["k_pages"].ndim == 5:   # (G, N, KH, page, D) stacked
             return jax.vmap(commit_node)(paged_cache, k_rows, v_rows)
         return commit_node(paged_cache, k_rows, v_rows)
     if isinstance(paged_cache, dict):
@@ -357,7 +356,7 @@ def set_block_table_rows(cache, slots, rows):
 
 def paged_cache_shardings(cache, mesh):
     """NamedSharding pytree for a paged model cache on a serving mesh:
-    page pools (…, page, KH, D) and scale tensors (…, KH) sharded BY KV
+    page pools (…, KH, page, D) and scale tensors (…, KH) sharded BY KV
     HEAD over the "model" axis (matching the kernel's shard_map specs —
     see ``kernels/paged_attention/ops.py``), block tables and anything
     else replicated.  KV-head dims the axis does not divide replicate.
@@ -370,8 +369,8 @@ def paged_cache_shardings(cache, mesh):
     def leaf(path, l):
         key = jax.tree_util.keystr(path)
         if ("k_pages" in key or "v_pages" in key) \
-                and l.shape[l.ndim - 2] % m == 0:
-            axes = (None,) * (l.ndim - 2) + ("model", None)
+                and l.shape[l.ndim - 3] % m == 0:
+            axes = (None,) * (l.ndim - 3) + ("model", None, None)
         elif ("k_scales" in key or "v_scales" in key) \
                 and l.shape[l.ndim - 1] % m == 0:
             axes = (None,) * (l.ndim - 1) + ("model",)
@@ -380,46 +379,3 @@ def paged_cache_shardings(cache, mesh):
         return NamedSharding(mesh, P(*axes))
 
     return jax.tree_util.tree_map_with_path(leaf, cache)
-
-
-# ---------------------------------------------------------------------------
-# Legacy single-slot path (readable baseline; the engine hot path is the
-# Pallas kernel in kernels/paged_attention)
-
-
-def paged_write(k_pages, v_pages, block_table, slot, pos, k_new, v_new):
-    """Write one token's K/V at logical position ``pos`` of ``slot``.
-    k_new/v_new: (kvh, hd).  bf16 pools only — the quantized write path
-    is ``repro.kvcache.paged_write_batch``."""
-    page = k_pages.shape[1]
-    page_idx = block_table[slot, pos // page]
-    off = pos % page
-    k_pages = jax.lax.dynamic_update_slice(
-        k_pages, k_new[None, None].astype(k_pages.dtype), (page_idx, off, 0, 0))
-    v_pages = jax.lax.dynamic_update_slice(
-        v_pages, v_new[None, None].astype(v_pages.dtype), (page_idx, off, 0, 0))
-    return k_pages, v_pages
-
-
-def paged_attention(q, k_pages, v_pages, block_table, slot, length,
-                    *, num_heads: int) -> jax.Array:
-    """Decode attention for one slot against its paged KV.
-
-    q: (H, hd).  Gathers the slot's pages (one take), then standard
-    masked attention over the gathered (max_pages·page) context.
-    """
-    bt = block_table[slot]                              # (max_pages,)
-    k = jnp.take(k_pages, bt, axis=0)                   # (P, page, kvh, hd)
-    v = jnp.take(v_pages, bt, axis=0)
-    p, page, kvh, hd = k.shape
-    k = k.reshape(p * page, kvh, hd)
-    v = v.reshape(p * page, kvh, hd)
-    g = num_heads // kvh
-    qg = q.reshape(kvh, g, hd)
-    scores = jnp.einsum("kgd,tkd->kgt", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) / (hd ** 0.5)
-    valid = jnp.arange(p * page) < length
-    scores = jnp.where(valid[None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("kgt,tkd->kgd", probs, v.astype(jnp.float32))
-    return o.reshape(num_heads, hd).astype(q.dtype)

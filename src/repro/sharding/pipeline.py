@@ -67,9 +67,8 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x_microbatches,
         # results live on the last stage only; replicate across stages
         return jax.lax.psum(outputs, axis)
 
-    from jax.experimental.shard_map import shard_map
     spec_p = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(per_device, mesh=mesh,
-                   in_specs=(spec_p, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(spec_p, P()), out_specs=P(),
+                       check_vma=False)
     return fn(params_stacked, x_microbatches)

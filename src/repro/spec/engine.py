@@ -192,9 +192,12 @@ class SpecEngine(SchedEngine):
                       fn=lambda f=f.name: getattr(self.spec_stats, f))
         m.gauge("spec_arm_info", "1, labelled with the speculation arm",
                 fn=lambda: 1.0, arm=self.spec_arm)
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._verify_jit = jax.jit(self._verify_impl, donate_argnums=donate,
+        self._verify_jit = jax.jit(self._verify_impl, donate_argnums=(1,),
                                    static_argnames=("max_pages",))
+        from repro.kernels.paged_attention.ops import check_paged_kernels
+        check_paged_kernels(self.cache, lm.cfg.attention.heads_padded,
+                            self.lm.dtype, decode=False,
+                            widths=(self.w_max,))
 
     # ------------------------------------------------------------------
     # device program

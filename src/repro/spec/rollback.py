@@ -47,7 +47,7 @@ def copy_page_device(cache, src: int, dst: int):
     def leaf(path, l):
         ks = jax.tree_util.keystr(path)
         if "k_pages" in ks or "v_pages" in ks:
-            if l.ndim == 5:                       # (G, N, page, KH, D)
+            if l.ndim == 5:                       # (G, N, KH, page, D)
                 return l.at[:, dst].set(l[:, src])
             return l.at[dst].set(l[src])
         if "k_scales" in ks or "v_scales" in ks:

@@ -20,12 +20,12 @@ from repro.kvcache.quant import _qmax_of
 
 def _pool(rng, n, page, kh, d, dtype):
     """Random page pool in ``dtype`` with per-page-per-kv-head scales."""
-    raw = rng.normal(size=(n, page, kh, d)).astype(np.float32)
+    raw = rng.normal(size=(n, kh, page, d)).astype(np.float32)
     if dtype == "bf16":
         return jnp.asarray(raw, jnp.bfloat16), None
     store = CacheSpec(dtype=dtype).store_dtype
-    sc = np.abs(raw).max(axis=(1, 3)) / _qmax_of(store) + 1e-9
-    q = raw / sc[:, None, :, None]
+    sc = np.abs(raw).max(axis=(2, 3)) / _qmax_of(store) + 1e-9
+    q = raw / sc[:, :, None, None]
     if dtype == "int8":
         q = np.clip(np.round(q), -127, 127)
     return jnp.asarray(q, store), jnp.asarray(sc, jnp.float32)

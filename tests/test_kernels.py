@@ -291,3 +291,21 @@ def test_wkv6_kernel_nonzero_state():
                                rtol=1e-4)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-4,
                                rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# interpret mode follows the backend
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False),
+                                            ("gpu", RuntimeError)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, expect):
+    """Kernels interpret on the CPU, compile on TPU, and refuse any
+    other backend instead of serving through the interpreter there."""
+    from repro import kernels
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="gpu"):
+            kernels.interpret_mode()
+    else:
+        assert kernels.interpret_mode() is expect

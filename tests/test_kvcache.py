@@ -120,8 +120,8 @@ def test_quantized_paged_matches_bf16_oracle(dtype):
     kp, vp, ks, vs, bt = paged_views(cache)
     o_q = paged_attention(q, kp, vp, bt, lengths, ks, vs)
     # bf16 truth: dequantize the pools and run the plain oracle
-    k_f = dequantize(kp, ks[:, None, :], axis=-1, dtype=jnp.float32)
-    v_f = dequantize(vp, vs[:, None, :], axis=-1, dtype=jnp.float32)
+    k_f = dequantize(kp, ks[:, :, None], axis=-1, dtype=jnp.float32)
+    v_f = dequantize(vp, vs[:, :, None], axis=-1, dtype=jnp.float32)
     o_f = paged_attention_ref(q.astype(jnp.float32), k_f, v_f, bt, lengths)
     tol = 0.06 if dtype == "int8" else 0.2       # softmax amplifies fp8 err
     np.testing.assert_allclose(np.asarray(o_q, np.float32),
@@ -146,7 +146,7 @@ def test_requant_growth_keeps_earlier_tokens():
                                   t, t)
     kp, _, ks, _, bt = paged_views(cache)
     final_step = float(ks[1, 0])                 # scale after all growths
-    got = np.asarray(kp[1, :5, 0], np.float32) * final_step   # (5, 8)
+    got = np.asarray(kp[1, 0, :5], np.float32) * final_step   # (5, 8)
     want = np.concatenate(toks)[:, 0]                         # (5, 8)
     assert np.abs(got - want).max() <= 2.5 * final_step + 1e-6
 

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_smoke_config
 from repro.configs.base import MoEConfig
@@ -132,7 +133,8 @@ def test_cp_decode_matches_eager():
     a = AttentionConfig(kind="gqa", num_heads=4, num_kv_heads=2,
                         head_dim=16, rope_theta=10_000.0)
     p = init_attention(jax.random.PRNGKey(0), 32, a, jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     b = 2
     cache = {"k": jnp.zeros((b, 64, 2, 16), jnp.float32),
              "v": jnp.zeros((b, 64, 2, 16), jnp.float32)}

@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 from repro.core.costmodel import (TIERS, CalibratedCostModel,
-                                  dispatch_estimate, predict)
+                                  dispatch_estimate, predict,
+                                  tier_for_devices)
 from repro.core.space import EfficiencyConfig
 from repro.obs import DISPATCH_KINDS, DispatchProfiler
 
@@ -336,3 +337,18 @@ def test_tuner_constructor_threads_calibration_into_evaluator():
     eff = EfficiencyConfig.default()
     uncal = Evaluator(cfg, TASKS["mmlu"], TIERS["v5e-1"])
     assert ev.evaluate(eff)[1] > uncal.evaluate(eff)[1]   # lat_ms scaled
+
+
+def test_tier_for_devices_by_device_kind():
+    """Peak rates come from the device kind: a v5e chip gets the v5e
+    tier, a chip count or kind with no tier gets none (no roofline share
+    is reported rather than another chip's)."""
+    from types import SimpleNamespace as NS
+    v5e, other = NS(device_kind="TPU v5 lite"), NS(device_kind="cpu")
+    assert tier_for_devices([v5e]) is TIERS["v5e-1"]
+    assert tier_for_devices([v5e] * 8) is TIERS["v5e-8"]
+    assert tier_for_devices([v5e] * 4) is None
+    assert tier_for_devices([other]) is None
+    prof = DispatchProfiler(enabled=True)
+    prof.record("admit", 0.0, 1.0, tokens=4)
+    assert all("attainment" not in a for a in prof.summary(None).values())

@@ -247,8 +247,8 @@ def _spec_rollback_invariants(ops):
     al = PageAllocator(n_pages, max_pages_per_slot=5, n_slots=n_slots)
     pc = PrefixCache(al, page)
     cache = {"kv": {
-        "k_pages": jnp.zeros((n_pages, page, 1, 4), jnp.bfloat16),
-        "v_pages": jnp.zeros((n_pages, page, 1, 4), jnp.bfloat16),
+        "k_pages": jnp.zeros((n_pages, 1, page, 4), jnp.bfloat16),
+        "v_pages": jnp.zeros((n_pages, 1, page, 4), jnp.bfloat16),
         "k_scales": jnp.zeros((n_pages, 1), jnp.float32),
         "v_scales": jnp.zeros((n_pages, 1), jnp.float32),
         "block_table": jnp.zeros((n_slots, 5), jnp.int32),

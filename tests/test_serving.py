@@ -19,8 +19,8 @@ def _rand_paged(rng, s, h, kvh, d, page, pps, dtype):
     """Random q + pools with distinct allocated pages per slot."""
     n = s * pps + 1
     q = jnp.asarray(rng.normal(size=(s, h, d)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n, page, kvh, d)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n, page, kvh, d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n, kvh, page, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n, kvh, page, d)), dtype)
     pool = list(rng.permutation(np.arange(1, n)))
     bt = jnp.asarray([[pool.pop() for _ in range(pps)] for _ in range(s)],
                      jnp.int32)
@@ -62,11 +62,12 @@ def test_paged_ref_matches_contiguous():
     v = jnp.asarray(rng.normal(size=(s, t, kvh, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(s, h, d)), jnp.float32)
     lengths = jnp.asarray([t // 2 + 3, t], jnp.int32)
-    # page it: slot i gets pages 1+i*pps .. (contiguous layout)
-    kp = jnp.concatenate([jnp.zeros((1, page, kvh, d)),
-                          k.reshape(s * pps, page, kvh, d)])
-    vp = jnp.concatenate([jnp.zeros((1, page, kvh, d)),
-                          v.reshape(s * pps, page, kvh, d)])
+    # page it: slot i gets pages 1+i*pps .. (contiguous layout), each
+    # page head-major (KH, page, D)
+    def paged(x):
+        x = x.reshape(s * pps, page, kvh, d).transpose(0, 2, 1, 3)
+        return jnp.concatenate([jnp.zeros((1, kvh, page, d)), x])
+    kp, vp = paged(k), paged(v)
     bt = (1 + jnp.arange(s * pps, dtype=jnp.int32)).reshape(s, pps)
     o = paged_attention_ref(q, kp, vp, bt, lengths)
     # dense reference
@@ -86,8 +87,8 @@ def test_paged_write_and_scatter():
     s, kvh, d, page, pps = 2, 2, 16, 4, 3
     n = s * pps + 1
     bt = (1 + jnp.arange(s * pps, dtype=jnp.int32)).reshape(s, pps)
-    cache = {"k_pages": jnp.zeros((n, page, kvh, d)),
-             "v_pages": jnp.zeros((n, page, kvh, d)),
+    cache = {"k_pages": jnp.zeros((n, kvh, page, d)),
+             "v_pages": jnp.zeros((n, kvh, page, d)),
              "block_table": bt}
     # batched prefill scatter: ragged lengths, padding -> null page
     t_pad = 8
@@ -99,7 +100,8 @@ def test_paged_write_and_scatter():
     for sl in range(s):
         ln = int(lengths[sl])
         for t in range(ln):
-            got = np.asarray(cache["k_pages"][bt[sl, t // page], t % page])
+            got = np.asarray(
+                cache["k_pages"][bt[sl, t // page], :, t % page])
             np.testing.assert_allclose(got, np.asarray(k_rows[sl, t]),
                                        atol=1e-6)
     # single-token batched write at per-slot positions
@@ -108,7 +110,8 @@ def test_paged_write_and_scatter():
     cache = paged_write_batch(cache, lengths, k_new, v_new)
     for sl in range(s):
         ln = int(lengths[sl])
-        got = np.asarray(cache["k_pages"][bt[sl, ln // page], ln % page])
+        got = np.asarray(
+            cache["k_pages"][bt[sl, ln // page], :, ln % page])
         np.testing.assert_allclose(got, np.asarray(k_new[sl]), atol=1e-6)
 
 

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.configs.base import SHAPES, ShapeConfig
@@ -16,7 +16,8 @@ from repro.sharding.rules import make_param_specs, spec_for_path
 
 
 def mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 CTX16 = {"model_size": 16, "data_size": 16}
@@ -58,8 +59,10 @@ def test_sanitize_drops_nondividing_axes():
     cfg = get_config("granite-moe-3b-a800m")
     lm = LM(cfg)
     params = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    ctx_mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+    ctx_mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     specs = make_param_specs(params, ctx_mesh)   # sizes 1: everything ok
     # emulate the 16×16 ctx directly through spec_for_path
     s = spec_for_path("embed/w", (49155, 1536), CTX16)

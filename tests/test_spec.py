@@ -21,11 +21,11 @@ from repro.spec import (AdaptiveDraftController, NgramDrafter, SpecEngine,
 
 
 def _quant_pool(rng, n, page, kh, d, dtype):
-    raw = rng.normal(size=(n, page, kh, d)).astype(np.float32)
+    raw = rng.normal(size=(n, kh, page, d)).astype(np.float32)
     if dtype == "bf16":
         return jnp.asarray(raw, jnp.bfloat16), None
-    sc = np.abs(raw).max(axis=(1, 3)) / 127.0 + 1e-9            # (N,KH)
-    q = np.clip(np.round(raw / sc[:, None, :, None]), -127, 127)
+    sc = np.abs(raw).max(axis=(2, 3)) / 127.0 + 1e-9            # (N,KH)
+    q = np.clip(np.round(raw / sc[:, :, None, None]), -127, 127)
     return jnp.asarray(q, jnp.int8), jnp.asarray(sc, jnp.float32)
 
 
@@ -69,8 +69,8 @@ def test_verify_width1_matches_decode_kernel():
     rng = np.random.default_rng(1)
     s_n, h, kh, d, page, p_n = 2, 4, 2, 16, 8, 3
     n_pages = 1 + s_n * p_n
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, kh, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, kh, d)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(n_pages, kh, page, d)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(n_pages, kh, page, d)), jnp.bfloat16)
     bt = jnp.asarray(np.arange(1, n_pages).reshape(s_n, p_n), jnp.int32)
     lengths = jnp.asarray([9, 17], jnp.int32)
     q = jnp.asarray(rng.normal(size=(s_n, 1, h, d)), jnp.float32)
@@ -79,9 +79,9 @@ def test_verify_width1_matches_decode_kernel():
     ver = paged_prefix_extend_attention(q, kp, vp, bt, lengths, ck, cv,
                                         jnp.ones((s_n,), jnp.int32))
     # decode path: write the token at lengths, attend with lengths+1
-    kp2 = kp.at[bt[jnp.arange(s_n), lengths // page],
+    kp2 = kp.at[bt[jnp.arange(s_n), lengths // page], :,
                 lengths % page].set(ck[:, 0])
-    vp2 = vp.at[bt[jnp.arange(s_n), lengths // page],
+    vp2 = vp.at[bt[jnp.arange(s_n), lengths // page], :,
                 lengths % page].set(cv[:, 0])
     dec = paged_attention(q[:, 0], kp2, vp2, bt, lengths + 1)
     np.testing.assert_allclose(np.asarray(ver[:, 0]), np.asarray(dec),
@@ -235,9 +235,9 @@ def test_ensure_exclusive_tail_cows_shared_page():
     p0 = al.alloc(0, 2)                         # slot 0: two pages
     al.assign(1, [p0[1]], 1)                    # slot 1 SHARES page p0[1]
     cache = {"kv": {
-        "k_pages": jnp.asarray(rng.normal(size=(8, page, kh, d)),
+        "k_pages": jnp.asarray(rng.normal(size=(8, kh, page, d)),
                                jnp.bfloat16),
-        "v_pages": jnp.asarray(rng.normal(size=(8, page, kh, d)),
+        "v_pages": jnp.asarray(rng.normal(size=(8, kh, page, d)),
                                jnp.bfloat16),
         "k_scales": jnp.asarray(rng.random((8, kh)), jnp.float32),
         "v_scales": jnp.asarray(rng.random((8, kh)), jnp.float32),
