@@ -299,6 +299,7 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=PREFIX_EXTEND_VMEM_BYTES),
         interpret=interpret,
+        name="paged_prefix_extend_pallas",
     )(*prefetch, q4, k_pages, v_pages, ck, cv)
     return out.reshape(s_n, kh, w_n, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(s_n, w_n, h, d)
@@ -335,5 +336,6 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, kh, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention_pallas",
     )(*prefetch, q.reshape(s_n, kh, g, d), k_pages, v_pages)
     return out.reshape(s_n, h, d)

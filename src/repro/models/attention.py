@@ -282,21 +282,24 @@ def attention_forward(p: dict, x: jax.Array, a: AttentionConfig, *,
         mask = None
 
     scale = 1.0 / jnp.sqrt(a.head_dim).astype(jnp.float32)
-    if use_flash and cross_x is None and mask is not None:
-        from repro.kernels.flash_attention.ops import flash_attention
-        o = flash_attention(q, k, v, causal=True, window=a.window)
-        o = o.reshape(b, s, a.heads_padded * a.head_dim)
-    elif cross_x is None and (attn_impl == "chunked"
-                              or (attn_impl == "auto" and s >= chunk_min)):
-        qg = q.reshape(b, s, kvh, g, a.head_dim)
-        o = chunked_attention(qg, k, v, causal=a.causal, window=a.window,
-                              scale=scale, q_block=q_block,
-                              kv_block=kv_block, unroll=unroll)
-        o = o.reshape(b, s, a.heads_padded * a.head_dim)
-    else:
-        qg = q.reshape(b, s, kvh, g, a.head_dim)
-        o = sdpa(qg, k, v, mask, scale)
-        o = o.reshape(b, s, a.heads_padded * a.head_dim)
+    with jax.named_scope("attn_kernel"):
+        if use_flash and cross_x is None and mask is not None:
+            from repro.kernels.flash_attention.ops import flash_attention
+            o = flash_attention(q, k, v, causal=True, window=a.window)
+            o = o.reshape(b, s, a.heads_padded * a.head_dim)
+        elif cross_x is None and (attn_impl == "chunked"
+                                  or (attn_impl == "auto"
+                                      and s >= chunk_min)):
+            qg = q.reshape(b, s, kvh, g, a.head_dim)
+            o = chunked_attention(qg, k, v, causal=a.causal,
+                                  window=a.window, scale=scale,
+                                  q_block=q_block, kv_block=kv_block,
+                                  unroll=unroll)
+            o = o.reshape(b, s, a.heads_padded * a.head_dim)
+        else:
+            qg = q.reshape(b, s, kvh, g, a.head_dim)
+            o = sdpa(qg, k, v, mask, scale)
+            o = o.reshape(b, s, a.heads_padded * a.head_dim)
     return linear_apply(p["wo"], _mask_pad_heads(o, a))
 
 
@@ -369,7 +372,9 @@ def attention_prefill(p: dict, x: jax.Array, a: AttentionConfig, cache: dict, *,
     from repro.sharding.ctx import maybe_constrain
     k = maybe_constrain(k, ("pod", "data"), None, None, None)
     v = maybe_constrain(v, ("pod", "data"), None, None, None)
-    return y, kvcache.prefill_write(cache, {"k": k, "v": v})
+    with jax.named_scope("kv_write"):
+        cache = kvcache.prefill_write(cache, {"k": k, "v": v})
+    return y, cache
 
 
 def attention_prefill_paged(p: dict, x: jax.Array, a: AttentionConfig,
@@ -429,21 +434,23 @@ def attention_prefill_paged(p: dict, x: jax.Array, a: AttentionConfig,
     k_new = maybe_constrain(k_new, ("pod", "data"), None, "model", None)
     v_new = maybe_constrain(v_new, ("pod", "data"), None, "model", None)
 
-    cache = kvcache.paged_scatter_prefill(cache, slot_ids, lengths,
-                                          k_new, v_new, starts)
-    cache = kvcache.constrain_paged_pools(cache)
+    with jax.named_scope("kv_write"):
+        cache = kvcache.paged_scatter_prefill(cache, slot_ids, lengths,
+                                              k_new, v_new, starts)
+        cache = kvcache.constrain_paged_pools(cache)
 
     # prefix < starts[b] streamed from the pages; the chunk's own
     # just-scattered rows are masked out in favour of the fresh values
-    kp, vp, k_sc, v_sc, bt = kvcache.paged_views(cache)
-    rows = bt[slot_ids]                                          # (B,P)
-    if use_kernel and max_pages is not None \
-            and max_pages < rows.shape[1]:
-        rows = rows[:, :max_pages]
-    o = paged_prefix_extend_attention(q, kp, vp, rows, starts,
-                                      k_new, v_new, lengths, k_sc, v_sc,
-                                      use_kernel=use_kernel, mesh=mesh,
-                                      tp_impl=tp_impl)
+    with jax.named_scope("attn_kernel"):
+        kp, vp, k_sc, v_sc, bt = kvcache.paged_views(cache)
+        rows = bt[slot_ids]                                      # (B,P)
+        if use_kernel and max_pages is not None \
+                and max_pages < rows.shape[1]:
+            rows = rows[:, :max_pages]
+        o = paged_prefix_extend_attention(q, kp, vp, rows, starts,
+                                          k_new, v_new, lengths, k_sc,
+                                          v_sc, use_kernel=use_kernel,
+                                          mesh=mesh, tp_impl=tp_impl)
     o = o.reshape(b, c, a.heads_padded * a.head_dim)
     y = linear_apply(p["wo"], _mask_pad_heads(o.astype(x.dtype), a))
     return y, cache
@@ -615,12 +622,14 @@ def attention_decode_paged(p: dict, x: jax.Array, a: AttentionConfig,
     k_new = maybe_constrain(k_new, None, "model", None)
     v_new = maybe_constrain(v_new, None, "model", None)
 
-    cache = kvcache.paged_write_batch(cache, pos, k_new, v_new)
-    cache = kvcache.constrain_paged_pools(cache)
-    k_pages, v_pages, k_sc, v_sc, bt = kvcache.paged_views(cache)
-    o = paged_attention(q, k_pages, v_pages, bt, pos + 1, k_sc, v_sc,
-                        use_kernel=use_kernel, mesh=mesh,
-                        tp_impl=tp_impl)                       # (S,H,D)
+    with jax.named_scope("kv_write"):
+        cache = kvcache.paged_write_batch(cache, pos, k_new, v_new)
+        cache = kvcache.constrain_paged_pools(cache)
+    with jax.named_scope("attn_kernel"):
+        k_pages, v_pages, k_sc, v_sc, bt = kvcache.paged_views(cache)
+        o = paged_attention(q, k_pages, v_pages, bt, pos + 1, k_sc, v_sc,
+                            use_kernel=use_kernel, mesh=mesh,
+                            tp_impl=tp_impl)                   # (S,H,D)
     o = o.reshape(b, 1, a.heads_padded * a.head_dim)
     y = linear_apply(p["wo"], _mask_pad_heads(o.astype(x.dtype), a))
     return y, cache

@@ -106,15 +106,19 @@ class LM:
         # no flag: int8 partial sums are exact in any reduce order.
         with quant_impl(impl), \
                 f32_accum(cfg.model_parallel > 1 and not train):
-            x = embedding_apply(params["embed"], tokens).astype(self.dtype)
-            x = maybe_constrain(x, ("pod", "data"), None, None)
+            with jax.named_scope("proj_mlp"):
+                x = embedding_apply(params["embed"], tokens).astype(
+                    self.dtype)
+                x = maybe_constrain(x, ("pod", "data"), None, None)
             cross_src = None
             if modality_input is not None and mode != "decode":
                 cross_src = self._encode_source(params, modality_input)
             x, new_cache, aux = stack_forward(
                 params["layers"], x, cfg, mode=mode, cache=cache, pos=pos,
                 cross_src=cross_src, train=train)
-            x = norm_apply(cfg.norm, params["final_norm"], x, cfg.norm_eps)
+            with jax.named_scope("proj_mlp"):
+                x = norm_apply(cfg.norm, params["final_norm"], x,
+                               cfg.norm_eps)
         return x, new_cache, aux
 
     # ------------------------------------------------------------------
@@ -173,14 +177,16 @@ class LM:
                                     cache=cache,
                                     modality_input=modality_input,
                                     train=False)
-        if lengths is None:
-            last = x[:, -1:]
-        else:
-            idx = jnp.clip(lengths - 1, 0, x.shape[1] - 1).astype(jnp.int32)
-            last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-        logits = last.astype(jnp.float32) @ self._head_w(params).astype(
-            jnp.float32)
-        return self._mask_pad_logits(logits[:, 0]), cache
+        with jax.named_scope("proj_mlp"):
+            if lengths is None:
+                last = x[:, -1:]
+            else:
+                idx = jnp.clip(lengths - 1, 0,
+                               x.shape[1] - 1).astype(jnp.int32)
+                last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+            logits = last.astype(jnp.float32) @ self._head_w(
+                params).astype(jnp.float32)
+            return self._mask_pad_logits(logits[:, 0]), cache
 
     def prefill_paged(self, params, tokens, cache, slot_ids, starts,
                       lengths, max_pages=None):
@@ -200,11 +206,12 @@ class LM:
             else (slot_ids, starts, lengths, max_pages)
         x, cache, _ = self.backbone(params, tokens, mode="prefill",
                                     cache=cache, pos=pos, train=False)
-        idx = jnp.clip(lengths - 1, 0, x.shape[1] - 1).astype(jnp.int32)
-        last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-        logits = last.astype(jnp.float32) @ self._head_w(params).astype(
-            jnp.float32)
-        return self._mask_pad_logits(logits[:, 0]), cache
+        with jax.named_scope("proj_mlp"):
+            idx = jnp.clip(lengths - 1, 0, x.shape[1] - 1).astype(jnp.int32)
+            last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+            logits = last.astype(jnp.float32) @ self._head_w(
+                params).astype(jnp.float32)
+            return self._mask_pad_logits(logits[:, 0]), cache
 
     def verify_paged(self, params, tokens, cache, stage, lengths, widths,
                      max_pages=None):
@@ -236,9 +243,10 @@ class LM:
         """token: (B,) int32; pos: scalar position -> (logits (B,V), cache)."""
         x, cache, _ = self.backbone(params, token[:, None], mode="decode",
                                     cache=cache, pos=pos, train=False)
-        logits = x[:, 0].astype(jnp.float32) @ self._head_w(params).astype(
-            jnp.float32)
-        return self._mask_pad_logits(logits), cache
+        with jax.named_scope("proj_mlp"):
+            logits = x[:, 0].astype(jnp.float32) @ self._head_w(
+                params).astype(jnp.float32)
+            return self._mask_pad_logits(logits), cache
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,18 @@ Block layout (pre-norm residual):
     x = x + mixer(norm1(x))            mixer ∈ {attn, mamba, rwkv6}
     [x = x + xattn(norm_x(x), src)]    (VLM / enc-dec blocks)
     x = x + mlp_or_moe(norm2(x))
+
+Named regions (``jax.named_scope``) mark what a serving program spends
+its device time on; an op belongs to the innermost region on its path:
+``kv_pool`` — the layer loop's slicing of each layer's cache out of the
+stacked pool and its write back (the loop itself, around the layer
+body; the loop slices the layer's weights too, and XLA keeps a few of
+those slices as ops of their own); ``proj_mlp`` — a layer's norms,
+projections, residuals and MLP (the whole layer body, around the two
+below), plus the embedding and the head; ``kv_write`` — the cache write
+of a layer's new keys and values; ``attn_kernel`` — the attention
+computation over the cache; ``sample`` — sampling, in the engines'
+programs (``repro.serve``).
 """
 from __future__ import annotations
 
@@ -313,8 +325,9 @@ def stack_forward(params: dict, x: jax.Array, cfg: ModelConfig, *,
                   cross_src: Optional[jax.Array] = None,
                   train: bool = True) -> Tuple[jax.Array, Optional[dict], dict]:
     def body_fn(x, gp, c):
-        return group_forward(gp, x, cfg, mode=mode, cache=c, pos=pos,
-                             cross_src=cross_src, train=train)
+        with jax.named_scope("proj_mlp"):
+            return group_forward(gp, x, cfg, mode=mode, cache=c, pos=pos,
+                                 cross_src=cross_src, train=train)
 
     if cfg.scan_layers:
         wrapped = _remat_wrap(body_fn, cfg.remat_policy if mode == "train"
@@ -334,8 +347,9 @@ def stack_forward(params: dict, x: jax.Array, cfg: ModelConfig, *,
                                    unroll=unroll)
             new_cache = None
         else:
-            x, (new_cache, auxs) = jax.lax.scan(scan_body, x, (params, cache),
-                                                unroll=unroll)
+            with jax.named_scope("kv_pool"):
+                x, (new_cache, auxs) = jax.lax.scan(
+                    scan_body, x, (params, cache), unroll=unroll)
         aux = {k: jnp.sum(v) for k, v in auxs.items()}
         return x, new_cache, aux
 

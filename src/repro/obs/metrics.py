@@ -124,7 +124,11 @@ class Counter(_Metric):
     kind = "counter"
 
     def inc(self, n: float = 1.0, **labels) -> None:
-        key = series_key(self.name, labels)
+        self.inc_series(series_key(self.name, labels), n)
+
+    def inc_series(self, key: str, n: float = 1.0) -> None:
+        """``inc`` for a series key already built with
+        :func:`series_key` (a hot loop's fixed label set)."""
         self._values[key] = self._values.get(key, 0.0) + n
 
 

@@ -1,12 +1,15 @@
-"""Request-lifecycle tracing in Chrome/Perfetto trace-event JSON.
+"""Request-lifecycle tracing in Chrome/Perfetto trace-event JSON, and
+the engines' program spans.
 
 A :class:`Tracer` records two kinds of tracks:
 
-* **pid 0 — "engine"**: one complete ("X") event per device dispatch
-  (``prefill_dispatch`` / ``decode_block`` / ``spec_round``), so the
-  engine's duty cycle and batching are visible at a glance, plus
-  counter ("C") tracks sampling queue depth, live slots and page-pool
-  occupancy at the same block boundaries;
+* **pid 0 — "engine"**: one complete ("X") event per program span
+  (:func:`span`: ``sched.step`` and the host phases inside it, among
+  them ``engine.decode.launch`` / ``engine.decode.wait``, which together
+  cover one fused decode dispatch and its sync) and per ``spec_round``,
+  so the engine's duty cycle and host phases are visible at a glance,
+  plus counter ("C") tracks sampling queue depth, live slots and
+  page-pool occupancy at the block boundaries;
 * **pid 1 — "requests"**: one thread (tid = request id) per request,
   carrying its lifecycle spans — ``request`` (submit → retire) encloses
   ``queue`` (submit → admit, re-opened after a preemption: the readmit
@@ -27,6 +30,13 @@ A disabled tracer (the default) is a no-op on every call.
 ``write()`` emits ``{"traceEvents": [...]}`` JSON that loads directly
 in https://ui.perfetto.dev or ``chrome://tracing``; a whole Poisson
 drive becomes one scrollable timeline.
+
+A program span (:func:`span`) times one host phase of an engine tick
+three ways at once: as a ``jax.profiler.TraceAnnotation``, so a running
+profile records it on the device trace's clock; as seconds and a count
+in the engine's registry (``serve_span_seconds_total{span=}``,
+``serve_spans_total{span=}``), always on; and as an engine-track event
+when the tracer is enabled.  It reads only the host clock.
 """
 from __future__ import annotations
 
@@ -34,8 +44,63 @@ import json
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import series_key
+
 PID_ENGINE = 0
 PID_REQUESTS = 1
+
+SPAN_SECONDS = "serve_span_seconds_total"
+SPAN_COUNT = "serve_spans_total"
+SPAN_SECONDS_HELP = "host seconds inside each program span"
+SPAN_COUNT_HELP = "program spans closed"
+_SPAN_KEYS: dict = {}           # span name -> its two series keys
+
+
+class _Span:
+    """One program span (see :func:`span`).  ``t0``/``t1`` are the host
+    clock readings at entry and exit, for callers that account the same
+    interval elsewhere; ``args`` set inside the span ride the tracer's
+    event."""
+    __slots__ = ("name", "metrics", "tracer", "args", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, metrics, tracer):
+        self.name = name
+        self.metrics = metrics
+        self.tracer = tracer
+        self.args = None
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        keys = _SPAN_KEYS.get(self.name)
+        if keys is None:
+            keys = _SPAN_KEYS[self.name] = (
+                series_key(SPAN_SECONDS, {"span": self.name}),
+                series_key(SPAN_COUNT, {"span": self.name}))
+        m = self.metrics
+        m.counter(SPAN_SECONDS, SPAN_SECONDS_HELP).inc_series(
+            keys[0], self.t1 - self.t0)
+        m.counter(SPAN_COUNT, SPAN_COUNT_HELP).inc_series(keys[1])
+        if self.tracer.enabled:
+            self.tracer.complete(self.name, 0, self.t0, self.t1,
+                                 pid=PID_ENGINE, args=self.args)
+        return False
+
+
+def span(name: str, metrics, tracer: Tracer) -> _Span:
+    """Context manager timing one host phase of an engine as a program
+    span, into ``metrics`` (the engine's ``MetricsRegistry``) and, when
+    enabled, ``tracer`` (see the module docstring).  Spans of one engine
+    nest or follow each other; they never partly overlap."""
+    return _Span(name, metrics, tracer)
 
 
 class Tracer:

@@ -187,7 +187,8 @@ class SchedEngine(PagedEngine):
         logits, cache = self.lm.prefill_paged(params, tokens, cache,
                                               slot_ids, starts, clens,
                                               max_pages=max_pages)
-        tok = _sample_batch(logits, temps, key)
+        with jax.named_scope("sample"):
+            tok = _sample_batch(logits, temps, key)
         return tok, cache
 
     # ------------------------------------------------------------------
@@ -565,116 +566,133 @@ class SchedEngine(PagedEngine):
         groups = {False: [], True: []}
         for slot, req in self._prefilling.items():
             groups[req.progress > 0].append((slot, req))
+        # one program span per host phase of each dispatch: prep (page
+        # growth, the padded batch), launch, wait (the sync), finish; the
+        # prefill phase's seconds are launch plus wait
         for cont in (False, True):
-            ready = []
-            for slot, req in groups[cont]:
-                if slot not in self._prefilling:
-                    continue
-                toks = self._sched_tokens(req)
-                clen = min(self._effective_chunk(),
-                           len(toks) - req.progress)
-                need = self.alloc.pages_needed(
-                    req.progress + clen, self.page_size) \
-                    - len(self.alloc.owned(slot))
-                if need > 0:
-                    self._grow(slot, need)
-                ready.append((slot, req, toks, clen))
-            # a later row's _grow may have preempted (or cancelled) an
-            # earlier ready row
-            ready = [r for r in ready if r[0] in self._prefilling]
-            if not ready:
+            if not groups[cont]:
                 continue
-            # chaos hook AFTER page growth, BEFORE any dispatch state is
-            # built: a raise here leaves the rows consistent (pages
-            # grown, progress untouched) for preempt-and-requeue
-            self._maybe_inject("prefill_chunk" if cont else "admit")
-            slots = np.asarray([s for s, _, _, _ in ready], np.int32)
-            clens = np.asarray([c for _, _, _, c in ready], np.int32)
-            starts = np.asarray([r.progress for _, r, _, _ in ready],
-                                np.int32)
-            cpad = _pow2_bucket(int(clens.max()))
-            tokens = np.zeros((len(ready), cpad), np.int32)
-            for i, (_, req, toks, clen) in enumerate(ready):
-                tokens[i, :clen] = toks[req.progress:req.progress + clen]
-            if cont:
-                # pow2-bucket the ROW count too (the chunk width cpad
-                # already is): ragged ready-row counts would otherwise
-                # retrace the continuation program.  Pad rows are inert —
-                # clen 0 routes their scatter to the null page, start 0
-                # skips every prefix page in the kernel, and the host
-                # loop below never reads their sampled token.
-                rpad = _pow2_bucket(len(ready), lo=1)
-                if rpad > len(ready):
-                    pad = rpad - len(ready)
-                    slots = np.concatenate(
-                        [slots, np.full(pad, slots[0], np.int32)])
-                    starts = np.concatenate(
-                        [starts, np.zeros(pad, np.int32)])
-                    clens = np.concatenate([clens, np.zeros(pad, np.int32)])
-                    tokens = np.concatenate(
-                        [tokens, np.zeros((pad, cpad), np.int32)])
-            self.key, sub = jax.random.split(self.key)
-            temps = jnp.asarray(self.temps[slots])
-            t0 = time.perf_counter()
-            if cont:
-                # page grid sized by the batch's deepest prefix (pow2-
-                # bucketed static), not the slot horizon: the fused
-                # kernel's step count scales with actual context
-                mp = min(_pow2_bucket(-(-int(starts.max())
-                                        // self.page_size), lo=1),
-                         self.alloc.max_pages_per_slot)
-                with self._mesh_ctx():
-                    tok, self.cache = self._chunk_jit(
-                        self.params, self.cache, jnp.asarray(tokens),
-                        jnp.asarray(slots), jnp.asarray(starts),
-                        jnp.asarray(clens), temps, sub, max_pages=mp)
-            else:
-                with self._mesh_ctx():
-                    tok, self.cache = self._admit_jit(
-                        self.params, self.cache, jnp.asarray(tokens),
-                        jnp.asarray(slots), jnp.asarray(clens), temps, sub)
-            tok = np.asarray(tok)            # <- sync (1 per chunk batch)
-            self.sync_count += 1
-            now = time.perf_counter()
-            self.t_prefill_s += now - t0
-            self.stats.chunks += 1
-            self._c_prefill_disp.inc()
-            tr = self.tracer
-            n_ready = len(ready)
-            if tr.enabled:
-                tr.complete("prefill_dispatch", 0, t0, now, pid=PID_ENGINE,
-                            args={"rows": n_ready, "cont": bool(cont),
-                                  "tokens": int(clens[:n_ready].sum())})
-            prof = self.profiler
-            if prof.enabled:
+            with self._span("sched.prefill.prep"):
+                batch = self._chunk_batch(groups[cont], cont)
+            if batch is None:
+                continue
+            ready, slots, clens, starts, cpad, tokens, sub, temps = batch
+            with self._span("sched.prefill.launch") as launch:
                 if cont:
-                    cost = (self._chunk_jit,
-                            (self.params, self.cache, tokens, slots, starts,
-                             clens, temps, sub), {"max_pages": mp})
+                    # page grid sized by the batch's deepest prefix (pow2-
+                    # bucketed static), not the slot horizon: the fused
+                    # kernel's step count scales with actual context
+                    mp = min(_pow2_bucket(-(-int(starts.max())
+                                            // self.page_size), lo=1),
+                             self.alloc.max_pages_per_slot)
+                    with self._mesh_ctx():
+                        tok, self.cache = self._chunk_jit(
+                            self.params, self.cache, jnp.asarray(tokens),
+                            jnp.asarray(slots), jnp.asarray(starts),
+                            jnp.asarray(clens), temps, sub, max_pages=mp)
                 else:
-                    cost = (self._admit_jit,
-                            (self.params, self.cache, tokens, slots, clens,
-                             temps, sub), None)
-                prof.record("prefill_chunk" if cont else "admit", t0, now,
-                            tokens=int(clens[:n_ready].sum()), rows=n_ready,
-                            bucket=cpad, ctx=int(starts.max()) + cpad,
-                            cost=cost)
-            for i, (slot, req, toks, clen) in enumerate(ready):
-                if tr.enabled:
-                    tr.complete(
-                        "prefill_chunk", req.rid, t0, now,
-                        args={"tokens": int(clen),
-                              "start": int(req.progress),
-                              "emitted": int(req.progress + clen
-                                             >= len(toks)
-                                             and not req.out_tokens)})
-                req.progress += clen
-                self.stats.prefill_tokens += clen
-                if req.progress >= len(toks):
-                    self._finish_prefill(slot, req, toks, int(tok[i]), now,
-                                         emitted)
-                else:
-                    self.lengths[slot] = req.progress
+                    with self._mesh_ctx():
+                        tok, self.cache = self._admit_jit(
+                            self.params, self.cache, jnp.asarray(tokens),
+                            jnp.asarray(slots), jnp.asarray(clens), temps,
+                            sub)
+            n_ready = len(ready)
+            with self._span("sched.prefill.wait") as wait:
+                tok = np.asarray(tok)        # <- sync (1 per chunk batch)
+                wait.args = {"rows": n_ready, "cont": bool(cont),
+                             "tokens": int(clens[:n_ready].sum())}
+            self.sync_count += 1
+            t0, now = launch.t0, wait.t1
+            self.t_prefill_s += now - t0
+            with self._span("sched.prefill.finish"):
+                self.stats.chunks += 1
+                self._c_prefill_disp.inc()
+                tr = self.tracer
+                prof = self.profiler
+                if prof.enabled:
+                    if cont:
+                        cost = (self._chunk_jit,
+                                (self.params, self.cache, tokens, slots,
+                                 starts, clens, temps, sub),
+                                {"max_pages": mp})
+                    else:
+                        cost = (self._admit_jit,
+                                (self.params, self.cache, tokens, slots,
+                                 clens, temps, sub), None)
+                    prof.record("prefill_chunk" if cont else "admit", t0,
+                                now, tokens=int(clens[:n_ready].sum()),
+                                rows=n_ready, bucket=cpad,
+                                ctx=int(starts.max()) + cpad, cost=cost)
+                for i, (slot, req, toks, clen) in enumerate(ready):
+                    if tr.enabled:
+                        tr.complete(
+                            "prefill_chunk", req.rid, t0, now,
+                            args={"tokens": int(clen),
+                                  "start": int(req.progress),
+                                  "emitted": int(req.progress + clen
+                                                 >= len(toks)
+                                                 and not req.out_tokens)})
+                    req.progress += clen
+                    self.stats.prefill_tokens += clen
+                    if req.progress >= len(toks):
+                        self._finish_prefill(slot, req, toks, int(tok[i]),
+                                             now, emitted)
+                    else:
+                        self.lengths[slot] = req.progress
+
+    def _chunk_batch(self, group: list, cont: bool):
+        """The host side of one prefill dispatch: page growth for each
+        row's chunk, then the padded batch.  None when no row is left."""
+        ready = []
+        for slot, req in group:
+            if slot not in self._prefilling:
+                continue
+            toks = self._sched_tokens(req)
+            clen = min(self._effective_chunk(),
+                       len(toks) - req.progress)
+            need = self.alloc.pages_needed(
+                req.progress + clen, self.page_size) \
+                - len(self.alloc.owned(slot))
+            if need > 0:
+                self._grow(slot, need)
+            ready.append((slot, req, toks, clen))
+        # a later row's _grow may have preempted (or cancelled) an
+        # earlier ready row
+        ready = [r for r in ready if r[0] in self._prefilling]
+        if not ready:
+            return None
+        # chaos hook AFTER page growth, BEFORE any dispatch state is
+        # built: a raise here leaves the rows consistent (pages
+        # grown, progress untouched) for preempt-and-requeue
+        self._maybe_inject("prefill_chunk" if cont else "admit")
+        slots = np.asarray([s for s, _, _, _ in ready], np.int32)
+        clens = np.asarray([c for _, _, _, c in ready], np.int32)
+        starts = np.asarray([r.progress for _, r, _, _ in ready],
+                            np.int32)
+        cpad = _pow2_bucket(int(clens.max()))
+        tokens = np.zeros((len(ready), cpad), np.int32)
+        for i, (_, req, toks, clen) in enumerate(ready):
+            tokens[i, :clen] = toks[req.progress:req.progress + clen]
+        if cont:
+            # pow2-bucket the ROW count too (the chunk width cpad
+            # already is): ragged ready-row counts would otherwise
+            # retrace the continuation program.  Pad rows are inert —
+            # clen 0 routes their scatter to the null page, start 0
+            # skips every prefix page in the kernel, and the host
+            # loop below never reads their sampled token.
+            rpad = _pow2_bucket(len(ready), lo=1)
+            if rpad > len(ready):
+                pad = rpad - len(ready)
+                slots = np.concatenate(
+                    [slots, np.full(pad, slots[0], np.int32)])
+                starts = np.concatenate(
+                    [starts, np.zeros(pad, np.int32)])
+                clens = np.concatenate([clens, np.zeros(pad, np.int32)])
+                tokens = np.concatenate(
+                    [tokens, np.zeros((pad, cpad), np.int32)])
+        self.key, sub = jax.random.split(self.key)
+        temps = jnp.asarray(self.temps[slots])
+        return ready, slots, clens, starts, cpad, tokens, sub, temps
 
     def _finish_prefill(self, slot: int, req: Request, toks: np.ndarray,
                         tok0: int, now: float, emitted: list) -> None:
@@ -733,36 +751,40 @@ class SchedEngine(PagedEngine):
         with all three knobs off this body is the pre-resilience tick
         verbatim."""
         emitted: List[tuple] = []
-        if not self.resilient:
-            self._admit_new()
-            self._dispatch_chunks(emitted)
-            if self.active:
-                self._ensure_decode_pages()
-                if self.active:
-                    self._dispatch_decode(emitted)
-            return emitted
-        now = time.perf_counter()
-        if self.ladder is not None:
-            self.ladder.update()
-        if self.max_request_s is not None:
-            self._expire_timeouts(now)
-        try:
-            self._admit_new()
-            self._dispatch_chunks(emitted)
-            if self.active:
-                self._ensure_decode_pages()
-                if self.active:
-                    self._dispatch_decode(emitted)
-        except TransientDispatchError as e:
-            self._recover_transient(e, time.perf_counter())
-        except OutOfPagesError as e:
-            self._recover_oom(e, time.perf_counter())
-        if not emitted and self.queue \
-                and not (self.active or self._prefilling):
-            # every queued request is in recovery backoff: yield briefly
-            # instead of spinning the host loop
-            time.sleep(0.0005)
+        with self._span("sched.step"):
+            if not self.resilient:
+                self._tick(emitted)
+                return emitted
+            now = time.perf_counter()
+            if self.ladder is not None:
+                self.ladder.update()
+            if self.max_request_s is not None:
+                self._expire_timeouts(now)
+            try:
+                self._tick(emitted)
+            except TransientDispatchError as e:
+                self._recover_transient(e, time.perf_counter())
+            except OutOfPagesError as e:
+                self._recover_oom(e, time.perf_counter())
+            if not emitted and self.queue \
+                    and not (self.active or self._prefilling):
+                # every queued request is in recovery backoff: yield
+                # briefly instead of spinning the host loop
+                time.sleep(0.0005)
         return emitted
+
+    def _tick(self, emitted: list) -> None:
+        """The tick's work, one program span per host phase (see
+        ``repro.obs.trace.span``): admission, prefill chunks, decode
+        page growth, the decode block."""
+        with self._span("sched.admit"):
+            self._admit_new()
+        self._dispatch_chunks(emitted)
+        if self.active:
+            with self._span("sched.grow"):
+                self._ensure_decode_pages()
+            if self.active:
+                self._dispatch_decode(emitted)
 
     def run_to_completion(self) -> Dict[int, Request]:
         while self.queue or self.active or self._prefilling:

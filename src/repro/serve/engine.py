@@ -36,7 +36,8 @@ import numpy as np
 
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.profile import DispatchProfiler
-from repro.obs.trace import PID_ENGINE, Tracer
+from repro.obs.trace import (PID_ENGINE, SPAN_COUNT, SPAN_COUNT_HELP,
+                             SPAN_SECONDS, SPAN_SECONDS_HELP, Tracer, span)
 from repro.resil.errors import OUTCOMES
 from repro.serve.paged import (PAGE, OutOfPagesError, PageAllocator,
                                scatter_prefill_cache, set_block_table_rows)
@@ -506,6 +507,9 @@ class PagedEngine(_EngineBase):
             "batched prefill / chunk dispatches")
         self._c_decode_disp = m.counter(
             "serve_decode_dispatches_total", "fused decode-block dispatches")
+        # program spans (repro.obs.trace.span): host phases of a tick
+        m.counter(SPAN_SECONDS, SPAN_SECONDS_HELP)
+        m.counter(SPAN_COUNT, SPAN_COUNT_HELP)
 
         # the old cache is dead the moment a dispatch returns — donate it
         # so the page pools aren't double-resident.  Donated on every
@@ -536,10 +540,13 @@ class PagedEngine(_EngineBase):
         staging cache is bf16 regardless of cfg.kv_cache_dtype: the
         scatter quantizes once, with exact per-page amax scales."""
         nb, t = tokens.shape
-        tmp = self.lm.init_cache(nb, t, kv_dtype="bfloat16")
+        with jax.named_scope("kv_write"):
+            tmp = self.lm.init_cache(nb, t, kv_dtype="bfloat16")
         logits, tmp = self.lm.prefill(params, tokens, tmp, lengths=plens)
-        cache = scatter_prefill_cache(cache, tmp, slot_ids, plens)
-        tok = _sample_batch(logits, temps, key)
+        with jax.named_scope("kv_write"):
+            cache = scatter_prefill_cache(cache, tmp, slot_ids, plens)
+        with jax.named_scope("sample"):
+            tok = _sample_batch(logits, temps, key)
         return tok, cache
 
     def _decode_impl(self, params, cache, tokens, lengths, active,
@@ -550,23 +557,27 @@ class PagedEngine(_EngineBase):
         2-vector of step stats ([tokens emitted, EOS fires]) rides the
         scan carry, and quantized-page requant events are counted by
         comparing scale leaves before/after — both read out at the same
-        block-boundary sync, never on their own."""
+        block-boundary sync, never on their own.  Named regions as in
+        ``repro.models.transformer``; sampling and the per-slot
+        bookkeeping are ``sample``."""
         eos, max_len = self.eos, self.max_len
 
         def real_step(carry):
             tokens, lengths, active, remaining, cache, key, stats = carry
             logits, cache = self.lm.decode_step(params, tokens, cache,
                                                 lengths)
-            key, sub = jax.random.split(key)
-            nxt = _sample_batch(logits, temps, sub)
-            nxt = jnp.where(active, nxt, tokens)
-            stats = stats + jnp.stack(
-                [jnp.sum(active.astype(jnp.int32)),
-                 jnp.sum((active & (nxt == eos)).astype(jnp.int32))])
-            lengths = jnp.where(active, lengths + 1, lengths)
-            remaining = jnp.where(active, remaining - 1, remaining)
-            done = (nxt == eos) | (remaining <= 0) | (lengths >= max_len - 1)
-            active = active & ~done
+            with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
+                nxt = _sample_batch(logits, temps, sub)
+                nxt = jnp.where(active, nxt, tokens)
+                stats = stats + jnp.stack(
+                    [jnp.sum(active.astype(jnp.int32)),
+                     jnp.sum((active & (nxt == eos)).astype(jnp.int32))])
+                lengths = jnp.where(active, lengths + 1, lengths)
+                remaining = jnp.where(active, remaining - 1, remaining)
+                done = (nxt == eos) | (remaining <= 0) \
+                    | (lengths >= max_len - 1)
+                active = active & ~done
             return (nxt, lengths, active, remaining, cache, key, stats)
 
         def step(carry, _):
@@ -587,6 +598,10 @@ class PagedEngine(_EngineBase):
 
     # ------------------------------------------------------------------
     # host loop
+
+    def _span(self, name: str):
+        """A program span of this engine (``repro.obs.trace.span``)."""
+        return span(name, self.metrics, self.tracer)
 
     def _maybe_inject(self, kind: str) -> None:
         """Chaos hook at the host side of a dispatch boundary: no-op
@@ -647,23 +662,21 @@ class PagedEngine(_EngineBase):
         self.cache = set_block_table_rows(self.cache, slot_ids,
                                           self.alloc.table[slot_ids])
         self.key, sub = jax.random.split(self.key)
-        t0 = time.perf_counter()
-        with self._mesh_ctx():
-            tok0, self.cache = self._admit_jit(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(slot_ids), jnp.asarray(plens),
-                jnp.asarray(self.temps[slot_ids]), sub)
-        tok0 = np.asarray(tok0)                  # <- sync (1 per admit batch)
+        with self._span("engine.prefill.launch") as launch:
+            with self._mesh_ctx():
+                tok0, self.cache = self._admit_jit(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(slot_ids), jnp.asarray(plens),
+                    jnp.asarray(self.temps[slot_ids]), sub)
+        with self._span("engine.prefill.wait") as wait:
+            tok0 = np.asarray(tok0)              # <- sync (1 per admit batch)
+            wait.args = {"rows": len(admitted), "tokens": int(plens.sum())}
         self.sync_count += 1
-        now = time.perf_counter()
+        t0, now = launch.t0, wait.t1
         self.t_prefill_s += now - t0
         self._c_prefill_disp.inc()
         self._c_tokens.inc(len(admitted))
         tr = self.tracer
-        if tr.enabled:
-            tr.complete("prefill_dispatch", 0, t0, now, pid=PID_ENGINE,
-                        args={"rows": len(admitted),
-                              "tokens": int(plens.sum())})
         prof = self.profiler
         if prof.enabled:
             prof.record("admit", t0, now, tokens=int(plens.sum()),
@@ -690,69 +703,77 @@ class PagedEngine(_EngineBase):
                 self._retire(req.slot, now)
 
     def _dispatch_decode(self, emitted: list):
+        """One fused decode block, in four program spans: ``prep`` (the
+        active mask, the key split, the uploads), ``launch`` (the jit
+        call), ``wait`` (the block's one sync) and ``emit`` (counters,
+        the emit loop, retirements).  The decode phase's seconds are
+        launch plus wait."""
         self._maybe_inject("decode_block")
-        active_mask = np.zeros((self.n_slots,), bool)
-        for slot in self.active:
-            active_mask[slot] = True
-        self.key, sub = jax.random.split(self.key)
-        t0 = time.perf_counter()
-        with self._mesh_ctx():
-            out = self._decode_jit(
-                self.params, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.lengths), jnp.asarray(active_mask),
-                jnp.asarray(self.remaining), jnp.asarray(self.temps), sub)
+        with self._span("engine.decode.prep"):
+            active_mask = np.zeros((self.n_slots,), bool)
+            for slot in self.active:
+                active_mask[slot] = True
+            self.key, sub = jax.random.split(self.key)
+            args = (jnp.asarray(self.last_tok), jnp.asarray(self.lengths),
+                    jnp.asarray(active_mask), jnp.asarray(self.remaining),
+                    jnp.asarray(self.temps), sub)
+        with self._span("engine.decode.launch") as launch:
+            with self._mesh_ctx():
+                out = self._decode_jit(self.params, self.cache, *args)
         self.cache = out[0]
-        # ONE sync for the whole K-token block (writable host copies);
-        # the device-counted step stats ride the same tuple out:
-        toks, emits, last, lengths, active, remaining, dstats = (
-            np.array(x) for x in out[1:])
+        with self._span("engine.decode.wait") as wait:
+            # ONE sync for the whole K-token block (writable host
+            # copies); the device-counted step stats ride the same tuple
+            toks, emits, last, lengths, active, remaining, dstats = (
+                np.array(x) for x in out[1:])
+            wait.args = {"rows": len(self.active),
+                         "steps": self.decode_block,
+                         "tokens": int(dstats[0])}
         self.sync_count += 1
-        now = time.perf_counter()
+        t0, now = launch.t0, wait.t1
         self.t_decode_s += now - t0
-        self.steps_dispatched += self.decode_block
-        self._c_decode_disp.inc()
-        self._c_decode_tokens.inc(int(dstats[0]))
-        self._c_tokens.inc(int(dstats[0]))
-        self._c_eos.inc(int(dstats[1]))
-        self._c_requant.inc(int(dstats[2]))
-        prof = self.profiler
-        if prof.enabled:
-            prof.record("decode_block", t0, now, tokens=int(dstats[0]),
-                        rows=len(self.active), steps=self.decode_block,
-                        bucket=self.decode_block,
-                        ctx=int(self.lengths.max()),
-                        cost=(self._decode_jit,
-                              (self.params, self.cache, self.last_tok,
-                               self.lengths, active_mask, self.remaining,
-                               self.temps, sub), None))
-        tr = self.tracer
-        if tr.enabled:
-            tr.complete("decode_block", 0, t0, now, pid=PID_ENGINE,
-                        args={"rows": len(self.active),
-                              "steps": self.decode_block,
-                              "tokens": int(dstats[0])})
-            tr.counter("utilization",
-                       {"queue_depth": len(self.queue),
-                        "slots_active": len(self.active),
-                        "pages_used": self.alloc.n_pages
-                        - len(self.alloc.free)}, ts=now)
-            for slot, req in self.active.items():
-                n = int(emits[:, slot].sum())
-                if n:
-                    tr.complete("decode_block", req.rid, t0, now,
-                                args={"tokens": n})
-        for i in range(self.decode_block):
+        with self._span("engine.decode.emit"):
+            self.steps_dispatched += self.decode_block
+            self._c_decode_disp.inc()
+            self._c_decode_tokens.inc(int(dstats[0]))
+            self._c_tokens.inc(int(dstats[0]))
+            self._c_eos.inc(int(dstats[1]))
+            self._c_requant.inc(int(dstats[2]))
+            prof = self.profiler
+            if prof.enabled:
+                prof.record("decode_block", t0, now,
+                            tokens=int(dstats[0]), rows=len(self.active),
+                            steps=self.decode_block,
+                            bucket=self.decode_block,
+                            ctx=int(self.lengths.max()),
+                            cost=(self._decode_jit,
+                                  (self.params, self.cache, self.last_tok,
+                                   self.lengths, active_mask,
+                                   self.remaining, self.temps, sub), None))
+            tr = self.tracer
+            if tr.enabled:
+                tr.counter("utilization",
+                           {"queue_depth": len(self.queue),
+                            "slots_active": len(self.active),
+                            "pages_used": self.alloc.n_pages
+                            - len(self.alloc.free)}, ts=now)
+                for slot, req in self.active.items():
+                    n = int(emits[:, slot].sum())
+                    if n:
+                        tr.complete("decode_block", req.rid, t0, now,
+                                    args={"tokens": n})
+            for i in range(self.decode_block):
+                for slot in list(self.active):
+                    if emits[i, slot]:
+                        req = self.active[slot]
+                        req.out_tokens.append(int(toks[i, slot]))
+                        req.pos += 1
+                        emitted.append((req.rid, int(toks[i, slot])))
+            self.last_tok, self.lengths, self.remaining = (last, lengths,
+                                                           remaining)
             for slot in list(self.active):
-                if emits[i, slot]:
-                    req = self.active[slot]
-                    req.out_tokens.append(int(toks[i, slot]))
-                    req.pos += 1
-                    emitted.append((req.rid, int(toks[i, slot])))
-        self.last_tok, self.lengths, self.remaining = (last, lengths,
-                                                       remaining)
-        for slot in list(self.active):
-            if not active[slot]:
-                self._retire(slot, now)
+                if not active[slot]:
+                    self._retire(slot, now)
 
     def step(self) -> List[tuple]:
         """One engine tick: batched admission (if anything is queued),

@@ -246,6 +246,7 @@ PAGED_COUNTERS = EAGER_COUNTERS | {
     "serve_decode_tokens_total", "serve_eos_total",
     "serve_kv_requant_events_total", "serve_prefill_dispatches_total",
     "serve_decode_dispatches_total",
+    "serve_span_seconds_total", "serve_spans_total",
 }
 PAGED_GAUGES = EAGER_GAUGES | {"serve_pages_free", "serve_pages_total"}
 
@@ -504,3 +505,99 @@ def test_telemetry_since_reports_per_drive_numbers(smoke):
     assert per_drive["sync_count"] == lifetime["sync_count"] \
         - lifetime_before["sync_count"]
     assert per_drive["policy"] == "fcfs"
+
+
+# ---------------------------------------------------------------------------
+# program spans (repro.obs.trace.span): host phases of a scheduler tick
+
+LEAF_SPANS = ("sched.admit", "sched.prefill.prep", "sched.prefill.launch",
+              "sched.prefill.wait", "sched.prefill.finish", "sched.grow",
+              "engine.decode.prep", "engine.decode.launch",
+              "engine.decode.wait", "engine.decode.emit")
+
+
+def _span_drive(smoke, tracer=None):
+    """A tiny SchedEngine drive that reaches every phase: a staging chunk,
+    continuation chunks (a prompt longer than the chunk), decode blocks
+    with page growth."""
+    from repro.sched import SchedEngine
+    lm, params, _ = smoke
+    rng = np.random.default_rng(3)
+    eng = SchedEngine(lm, params, n_slots=2, max_len=64, seed=0,
+                      page_size=8, decode_block=4, prefill_chunk=16,
+                      policy="fcfs", prefix_cache=True, tracer=tracer)
+    prompts = [rng.integers(0, lm.cfg.vocab_size, (n,)).tolist()
+               for n in (40, 5, 12)]
+    return eng, _drive(eng, prompts, max_new=9)
+
+
+def _span_counters(eng) -> dict:
+    c = eng.metrics.snapshot()["counters"]
+    out = {}
+    for k, v in c.items():
+        if k.startswith(("serve_span_seconds_total{",
+                         "serve_spans_total{")):
+            family, name = k.split('{span="')
+            out.setdefault(name.rstrip('"}'), {})[family] = v
+    return out
+
+
+def test_every_leaf_span_counts_within_the_tick(smoke):
+    """Each leaf span's counters move on a drive that reaches every
+    phase, and the leaf spans, which never overlap, take no more than
+    the ticks (sched.step) that hold them."""
+    eng, _ = _span_drive(smoke)
+    spans = _span_counters(eng)
+    for name in LEAF_SPANS:
+        assert spans[name]["serve_spans_total"] > 0, name
+        assert spans[name]["serve_span_seconds_total"] > 0, name
+    tick = spans["sched.step"]
+    assert tick["serve_spans_total"] >= spans["sched.admit"][
+        "serve_spans_total"]
+    leaf_s = sum(spans[n]["serve_span_seconds_total"] for n in LEAF_SPANS)
+    assert leaf_s <= tick["serve_span_seconds_total"]
+    # launch + wait are the phases' seconds (serve_phase_seconds_total)
+    c = eng.metrics.snapshot()["counters"]
+    for phase, head in (("decode", "engine.decode"),
+                        ("prefill", "sched.prefill")):
+        both = sum(spans[f"{head}.{p}"]["serve_span_seconds_total"]
+                   for p in ("launch", "wait"))
+        got = c[f'serve_phase_seconds_total{{phase="{phase}"}}']
+        assert both <= got and got - both < 1e-3 * spans[f"{head}.wait"][
+            "serve_spans_total"], phase
+
+
+def test_chrome_tracer_changes_no_sync_and_no_token(smoke):
+    """The Chrome tracer on or off: the same host syncs, the same greedy
+    streams, the same span counts."""
+    off, out_off = _span_drive(smoke)
+    on, out_on = _span_drive(smoke, Tracer(enabled=True))
+    assert on.sync_count == off.sync_count
+    assert out_on == out_off
+    counts = {k: v["serve_spans_total"] for k, v in
+              _span_counters(off).items()}
+    assert counts == {k: v["serve_spans_total"] for k, v in
+                      _span_counters(on).items()}
+
+
+def test_chrome_trace_carries_the_spans_on_the_engine_track(smoke):
+    """Every program span is an X event on the engine track, one per
+    span closed, each leaf inside a tick; the hand-written dispatch
+    events they replace are gone."""
+    from repro.obs.trace import PID_ENGINE
+    tr = Tracer(enabled=True)
+    eng, _ = _span_drive(smoke, tr)
+    ev = [e for e in tr.events if e.get("pid") == PID_ENGINE
+          and e.get("ph") == "X"]
+    names = [e["name"] for e in ev]
+    for name, v in _span_counters(eng).items():
+        assert names.count(name) == v["serve_spans_total"], name
+    assert "prefill_dispatch" not in names and "decode_block" not in names
+    ticks = [(e["ts"], e["ts"] + e["dur"]) for e in ev
+             if e["name"] == "sched.step"]
+    for e in ev:
+        if e["name"] in LEAF_SPANS:
+            assert any(a <= e["ts"] and e["ts"] + e["dur"] <= b + 1e-3
+                       for a, b in ticks), e["name"]
+    wait = [e for e in ev if e["name"] == "engine.decode.wait"]
+    assert all(e["args"]["steps"] == 4 for e in wait)
