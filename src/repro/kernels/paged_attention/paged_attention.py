@@ -6,15 +6,20 @@ the online-softmax running state (m, l, acc) lives in VMEM scratch across
 page steps — the flash-decoding recurrence of serve/decode_attn.py, but per
 page instead of per shard.
 
-Pages are STREAMED, never gathered: the block table and per-slot lengths
-ride in as scalar-prefetch operands (``PrefetchScalarGridSpec``), and the
-K/V BlockSpec index maps look the physical page id up as
-``block_table[slot, page_block]`` — each grid step DMAs exactly one
-(page_size, head_dim) tile from HBM.  Pools are HEAD-MAJOR,
-(N, KH, page, D): the tile is then the pool's own last two dims, the
-only (page, D) block the TPU compiler accepts for every page size and
-dtype (a page-major (N, page, KH, D) pool would need a (page, 1, D)
-block, whose second-minor dim of 1 Mosaic refuses).
+Pages are STREAMED, never gathered: the layer index, the block table and
+per-slot lengths ride in as scalar-prefetch operands
+(``PrefetchScalarGridSpec``), and the K/V BlockSpec index maps look the
+tile up as ``(layer, block_table[slot, page_block], kv_head)`` — each
+grid step DMAs exactly one (page_size, head_dim) tile from HBM.  Pools
+are HEAD-MAJOR and stacked over layers, (L, N, KH, page, D): the tile is
+then the pool's own last two dims, the only (page, D) block the TPU
+compiler accepts for every page size and dtype (a page-major
+(N, page, KH, D) pool would need a (page, 1, D) block, whose
+second-minor dim of 1 Mosaic refuses).  The serving programs' layer loop
+carries the whole stack and passes each layer's index, so no layer's
+pool is ever sliced out, re-laid or copied for the kernel
+(``models/transformer.stack_forward``); a 4-D (N, KH, page, D) pool is
+a one-layer stack at layer 0.
 
 GQA is handled like kernels/flash_attention: the kv-head grid axis selects
 one stored head, the q block carries that head's ``group`` query heads, and
@@ -23,11 +28,11 @@ skipped with ``pl.when`` (their grid steps fetch the null page but run no
 compute); partially-filled last pages are masked via a broadcasted iota
 against the slot's length.  fp32 accumulation throughout.
 
-Quantized pools (int8 / fp8-e4m3, ``repro.kvcache``): the per-page-per-
-kv-head fp32 amax scales ride in as two extra scalar-prefetch operands,
-flattened to 1-D (N·KH,) — a 2-D (N, KH) SMEM array pads every row to
-512 B and a pool of a few thousand pages overflows the 1 MiB SMEM — and
-dequant is FUSED into the online-softmax
+Quantized pools (int8 / fp8-e4m3, ``repro.kvcache``): the layer's
+per-page-per-kv-head fp32 amax scales ride in as two extra
+scalar-prefetch operands, flattened to 1-D (N·KH,) — a 2-D (N, KH) SMEM
+array pads every row to 512 B and a pool of a few thousand pages
+overflows the 1 MiB SMEM — and dequant is FUSED into the online-softmax
 inner loop — the K scale folds into the score scale (``(q·k_q)·s·k_s``)
 and the V scale folds into the p·v accumulation (``(p·v_q)·v_s``), so no
 dequantized page is ever materialized in HBM or VMEM.  Streaming int8
@@ -61,10 +66,10 @@ PREFIX_EXTEND_VMEM_BYTES = 48 * 2 ** 20
 def _paged_kernel(*refs, scale: float, page_size: int, n_page_blocks: int,
                   n_kv_heads: int, quantized: bool):
     if quantized:
-        (bt_ref, len_ref, ks_ref, vs_ref,
+        (_, bt_ref, len_ref, ks_ref, vs_ref,
          q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr) = refs
     else:
-        (bt_ref, len_ref,
+        (_, bt_ref, len_ref,
          q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr) = refs
     s_i = pl.program_id(0)
     k_i = pl.program_id(1)
@@ -141,11 +146,11 @@ def _prefix_extend_kernel(*refs, scale: float, page_size: int,
     that is what replaces the eager full-horizon gather of the old
     ``attention_prefill_paged`` (now the oracle in ref.py)."""
     if quantized:
-        (bt_ref, len_ref, wid_ref, ks_ref, vs_ref,
+        (_, bt_ref, len_ref, wid_ref, ks_ref, vs_ref,
          q_ref, k_ref, v_ref, ck_ref, cv_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
     else:
-        (bt_ref, len_ref, wid_ref,
+        (_, bt_ref, len_ref, wid_ref,
          q_ref, k_ref, v_ref, ck_ref, cv_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
     s_i = pl.program_id(0)
@@ -222,22 +227,43 @@ def _tile_spec(rows: int, d: int) -> pl.BlockSpec:
 
 
 def _page_spec(page: int, d: int, p_n: int) -> pl.BlockSpec:
-    """One (page, D) tile of a head-major (N, KH, page, D) pool: physical
-    page ``block_table[slot, page_block]``.  Index maps see every
-    scalar-prefetch operand after the grid coordinates; only the block
-    table is consulted.  The prefix-extend grid runs one step past the
-    table (its chunk step), hence the clamp."""
+    """One (page, D) tile of a stacked head-major (L, N, KH, page, D)
+    pool: layer ``layer[0]``, physical page ``block_table[slot,
+    page_block]``.  Index maps see every scalar-prefetch operand after the
+    grid coordinates; only the layer and the block table are consulted.
+    The prefix-extend grid runs one step past the table (its chunk step),
+    hence the clamp."""
     return pl.BlockSpec(
-        (None, None, page, d),
-        lambda s, k, p, bt, *_: (bt[s, jnp.minimum(p, p_n - 1)], k, 0, 0))
+        (None, None, None, page, d),
+        lambda s, k, p, layer, bt, *_: (
+            layer[0], bt[s, jnp.minimum(p, p_n - 1)], k, 0, 0))
 
 
-def _prefetch(block_table, per_slot, k_scales, v_scales):
-    """Scalar-prefetch operands: the block table, the per-slot int32
-    vectors, and for quantized pools the (N, KH) scales flattened to
-    (N·KH,) (1-D SMEM does not pad rows)."""
-    ops = [block_table.astype(jnp.int32)] + [x.astype(jnp.int32)
-                                             for x in per_slot]
+def _stacked(k_pages, v_pages, k_scales, v_scales, layer):
+    """Pools as a layer stack (L, N, KH, page, D) and the layer's scales:
+    a 4-D pool is a one-layer stack, addressed at layer 0.  Quantized
+    scales (L, N, KH) are sliced to the layer's (N, KH): the whole stack's
+    would overflow SMEM, and the slice is a few KiB."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+    if layer is None:
+        assert k_pages.shape[0] == 1, "a stacked pool needs its layer index"
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32)
+    if k_scales is not None:
+        k_scales = jax.lax.dynamic_index_in_dim(k_scales, layer, 0, False)
+        v_scales = jax.lax.dynamic_index_in_dim(v_scales, layer, 0, False)
+    return k_pages, v_pages, k_scales, v_scales, layer.reshape(1)
+
+
+def _prefetch(layer, block_table, per_slot, k_scales, v_scales):
+    """Scalar-prefetch operands: the layer index (1,), the block table,
+    the per-slot int32 vectors, and for quantized pools the layer's
+    (N, KH) scales flattened to (N·KH,) (1-D SMEM does not pad rows)."""
+    ops = [layer, block_table.astype(jnp.int32)] + [
+        x.astype(jnp.int32) for x in per_slot]
     if k_scales is not None:
         ops += [k_scales.astype(jnp.float32).reshape(-1),
                 v_scales.astype(jnp.float32).reshape(-1)]
@@ -245,7 +271,7 @@ def _prefetch(block_table, per_slot, k_scales, v_scales):
 
 
 def _check_pools(q_heads, k_pages, k_scales):
-    _, kh, page, _ = k_pages.shape
+    kh, page = k_pages.shape[-3:-1]
     assert q_heads % kh == 0, (q_heads, kh)
     quantized = k_scales is not None
     assert quantized == (k_pages.dtype not in (jnp.bfloat16, jnp.float32)), \
@@ -256,14 +282,15 @@ def _check_pools(q_heads, k_pages, k_scales):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
                                prefix_lens, chunk_k, chunk_v, widths,
-                               k_scales=None, v_scales=None, *,
+                               k_scales=None, v_scales=None, layer=None, *,
                                interpret: bool = False) -> jax.Array:
     """q: (S,W,H,D) — W query positions per slot at logical positions
     ``prefix_lens[s] + [0, W)``; chunk_k/chunk_v: (S,W,KH,D) fresh K/V
-    attended causally up to ``widths[s]``; everything else as
-    :func:`paged_attention_pallas` -> (S,W,H,D).  Spec verify calls this
-    at W = k+1 (prefix = committed lengths), chunked prefill at W =
-    chunk width (prefix = the chunk's page-aligned start)."""
+    attended causally up to ``widths[s]``; everything else (pools, scales,
+    ``layer``) as :func:`paged_attention_pallas` -> (S,W,H,D).  Spec
+    verify calls this at W = k+1 (prefix = committed lengths), chunked
+    prefill at W = chunk width (prefix = the chunk's page-aligned
+    start)."""
     s_n, w_n, h, d = q.shape
     kh, page, quantized = _check_pools(h, k_pages, k_scales)
     g = h // kh
@@ -276,7 +303,9 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
     # chunk K/V head-major like the pools: a (W, D) tile per kv head
     ck = chunk_k.transpose(0, 2, 1, 3)
     cv = chunk_v.transpose(0, 2, 1, 3)
-    prefetch = _prefetch(block_table, (prefix_lens, widths),
+    k_pages, v_pages, k_scales, v_scales, layer = _stacked(
+        k_pages, v_pages, k_scales, v_scales, layer)
+    prefetch = _prefetch(layer, block_table, (prefix_lens, widths),
                          k_scales, v_scales)
     kv_spec = _page_spec(page, d, p_n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -307,17 +336,22 @@ def paged_prefix_extend_pallas(q, k_pages, v_pages, block_table,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths,
-                           k_scales=None, v_scales=None, *,
+                           k_scales=None, v_scales=None, layer=None, *,
                            interpret: bool = False) -> jax.Array:
-    """q: (S,H,D); k_pages/v_pages: (N,KH,page,D); block_table: (S,P) int32;
-    lengths: (S,) int32 -> (S,H,D).  Quantized pools additionally take
-    k_scales/v_scales: (N,KH) fp32 per-page-per-kv-head amax scales."""
+    """q: (S,H,D); k_pages/v_pages: (L,N,KH,page,D) layer stacks, read at
+    layer ``layer`` (int32 scalar), or (N,KH,page,D) one-layer pools
+    (``layer`` None or 0); block_table: (S,P) int32; lengths: (S,) int32
+    -> (S,H,D).  Quantized pools additionally take k_scales/v_scales:
+    (L,N,KH) — (N,KH) beside a 4-D pool — fp32 per-page-per-kv-head amax
+    scales."""
     s_n, h, d = q.shape
     kh, page, quantized = _check_pools(h, k_pages, k_scales)
     g = h // kh
     p_n = block_table.shape[1]
     scale = 1.0 / (d ** 0.5)
-    prefetch = _prefetch(block_table, (lengths,), k_scales, v_scales)
+    k_pages, v_pages, k_scales, v_scales, layer = _stacked(
+        k_pages, v_pages, k_scales, v_scales, layer)
+    prefetch = _prefetch(layer, block_table, (lengths,), k_scales, v_scales)
     kv_spec = _page_spec(page, d, p_n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),

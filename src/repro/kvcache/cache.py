@@ -14,6 +14,12 @@ Paged node:       {"k_pages"/"v_pages": (N,KH,page,D)  (head-major: a
                    [, "k_scales"/"v_scales": (N,KH) f32]
                    "block_table": (n_slots, pages_per_slot) int32}
 
+Inside the serving programs' layer loop (``models/transformer``) a paged
+node holds the WHOLE layer stack of pools, (L,N,KH,page,D) and (L,N,KH)
+scales, beside the layer's own block table and ``"layer"``, an int32
+scalar: the writes below and the paged kernels address the layer's pool
+in place at that index, so no layer's pool is sliced out or copied.
+
 Quantized scales are fp32 amax scales: per (batch, position, kv_head)
 for contiguous caches, per (page, kv_head) for paged pools.  Paged page
 scales are *running* maxima — a decode write that raises a page's amax
@@ -166,10 +172,28 @@ def paged_views(cache: dict):
             cache["block_table"])
 
 
-def _quant_token_write(pages, scales, pidx, off, new):
+def _lead(cache: dict) -> tuple:
+    """Index prefix that picks the node's layer out of stacked pools and
+    scales: ``(layer,)`` inside the layer loop, ``()`` for one layer's."""
+    return (cache["layer"],) if "layer" in cache else ()
+
+
+def _rows_at(lead: tuple, pidx, off, kv_heads: int) -> tuple:
+    """Index of the (…, KH) head rows at page ``pidx``, offset ``off``
+    (same shapes) of a pool: one D-long row per kv head.  A head-major
+    pool's (page, D) tile keeps D minor, so each update is a row of the
+    layout the paged kernels read, and the scatter runs in that layout;
+    a (KH, D) window per token would make XLA re-lay the pool with KH
+    second-minor and copy it back for the kernel."""
+    kh = jnp.arange(kv_heads)
+    return lead + (pidx[..., None], kh, off[..., None])
+
+
+def _quant_token_write(pages, scales, pidx, off, new, lead=()):
     """Append one quantized token per slot at (pidx, off), growing the
     page's running amax scale and requantizing the page when it grows.
-    pages: (N,KH,page,D); scales: (N,KH); new: (S,KH,D) bf16.
+    pages: (N,KH,page,D); scales: (N,KH); new: (S,KH,D) bf16; stacked
+    (L,…) pools and scales with ``lead`` = (layer,).
 
     A write at offset 0 RESETS the page's scale instead of growing it: a
     page's first token is always written at offset 0 (allocations, lazy
@@ -188,7 +212,7 @@ def _quant_token_write(pages, scales, pidx, off, new):
     s_n = pidx.shape[0]
     qmax = _qmax_of(pages.dtype)
     amax = jnp.max(jnp.abs(new.astype(jnp.float32)), axis=-1)    # (S,KH)
-    old = scales[pidx]                                           # (S,KH)
+    old = scales[lead + (pidx,)]                                 # (S,KH)
     fresh = (off == 0)[:, None]                                  # (S,1)
     ns = jnp.where(fresh, amax / qmax, jnp.maximum(old, amax / qmax))
     tok = quantize_with_scale(new, ns, pages.dtype, axis=-1)     # (S,KH,D)
@@ -198,18 +222,18 @@ def _quant_token_write(pages, scales, pidx, off, new):
     grew = jnp.any((ns > old) & (old > 0) & ~fresh & (pidx != 0)[:, None])
 
     def rescale_pages(pages):
-        pg = pages[pidx]                                         # (S,KH,page,D)
+        pg = pages[lead + (pidx,)]                          # (S,KH,page,D)
         pg = requantize(pg, old[:, :, None], ns[:, :, None], axis=-1)
         pg = pg.at[jnp.arange(s_n), :, off].set(tok)
         # duplicate pidx entries only ever alias the null page (free
         # slots); whichever garbage write wins there is masked away
-        return pages.at[pidx].set(pg)
+        return pages.at[lead + (pidx,)].set(pg)
 
     def append_only(pages):
-        return pages.at[pidx, :, off].set(tok)
+        return pages.at[_rows_at(lead, pidx, off, tok.shape[1])].set(tok)
 
     pages = jax.lax.cond(grew, rescale_pages, append_only, pages)
-    return pages, scales.at[pidx].set(ns)
+    return pages, scales.at[lead + (pidx,)].set(ns)
 
 
 def paged_write_batch(cache: dict, positions: jax.Array,
@@ -223,7 +247,8 @@ def paged_write_batch(cache: dict, positions: jax.Array,
     rejected drafts never touch a live page, so rollback is exact even
     for quantized pools whose scales a rejected tail could have grown)."""
     kp, vp, ks, vs, bt = paged_views(cache)
-    page = kp.shape[2]
+    lead = _lead(cache)
+    page = kp.shape[-2]
     s_n = positions.shape[0]
     lpage = jnp.minimum(positions // page, bt.shape[1] - 1)      # pad-safe
     pidx = bt[jnp.arange(s_n), lpage]                            # (S,)
@@ -233,28 +258,29 @@ def paged_write_batch(cache: dict, positions: jax.Array,
         off = jnp.where(mask, off, 0)
     out = dict(cache)
     if ks is None:
-        out["k_pages"] = kp.at[pidx, :, off].set(k_new.astype(kp.dtype))
-        out["v_pages"] = vp.at[pidx, :, off].set(v_new.astype(vp.dtype))
+        at = _rows_at(lead, pidx, off, kp.shape[-3])
+        out["k_pages"] = kp.at[at].set(k_new.astype(kp.dtype))
+        out["v_pages"] = vp.at[at].set(v_new.astype(vp.dtype))
         return out
     out["k_pages"], out["k_scales"] = _quant_token_write(kp, ks, pidx, off,
-                                                         k_new)
+                                                         k_new, lead)
     out["v_pages"], out["v_scales"] = _quant_token_write(vp, vs, pidx, off,
-                                                         v_new)
+                                                         v_new, lead)
     return out
 
 
-def _quant_scatter(pages, scales, pidx, off, rows, amax):
+def _quant_scatter(pages, scales, pidx, off, rows, amax, lead=()):
     """Scatter a prefill's rows into pages with fresh per-page scales.
     pidx/off: (B,T); rows: (B,T,KH,D); amax: (B,T,KH), zeroed at
-    invalid (padding) positions."""
+    invalid (padding) positions; ``lead`` as in ``_quant_token_write``."""
     qmax = _qmax_of(pages.dtype)
     # reset-then-max: scattered pages get exactly this prefill's amax
     # (stale scales from a released slot would otherwise linger)
-    scales = scales.at[pidx].set(0.0)
-    scales = scales.at[pidx].max(amax / qmax)
-    per_tok = scales[pidx]                                       # (B,T,KH)
+    scales = scales.at[lead + (pidx,)].set(0.0)
+    scales = scales.at[lead + (pidx,)].max(amax / qmax)
+    per_tok = scales[lead + (pidx,)]                             # (B,T,KH)
     q = quantize_with_scale(rows, per_tok, pages.dtype, axis=-1)
-    return pages.at[pidx, :, off].set(q), scales
+    return pages.at[_rows_at(lead, pidx, off, q.shape[-2])].set(q), scales
 
 
 def paged_scatter_prefill(cache: dict, slot_ids: jax.Array,
@@ -276,8 +302,9 @@ def paged_scatter_prefill(cache: dict, slot_ids: jax.Array,
     scheduler's chunked prefill enforces chunk % page_size == 0.
     """
     kp, vp, ks, vs, bt = paged_views(cache)
+    lead = _lead(cache)
     b, t = k_rows.shape[:2]
-    page = kp.shape[2]
+    page = kp.shape[-2]
     tpos = jnp.arange(t)[None, :]                                # (1,T)
     if starts is None:
         starts = jnp.zeros((b,), jnp.int32)
@@ -289,16 +316,17 @@ def paged_scatter_prefill(cache: dict, slot_ids: jax.Array,
     off = jnp.where(valid, apos % page, 0)
     out = dict(cache)
     if ks is None:
-        out["k_pages"] = kp.at[pidx, :, off].set(k_rows.astype(kp.dtype))
-        out["v_pages"] = vp.at[pidx, :, off].set(v_rows.astype(vp.dtype))
+        at = _rows_at(lead, pidx, off, kp.shape[-3])
+        out["k_pages"] = kp.at[at].set(k_rows.astype(kp.dtype))
+        out["v_pages"] = vp.at[at].set(v_rows.astype(vp.dtype))
         return out
     vm = valid[..., None].astype(jnp.float32)                    # (B,T,1)
     k_amax = jnp.max(jnp.abs(k_rows.astype(jnp.float32)), axis=-1) * vm
     v_amax = jnp.max(jnp.abs(v_rows.astype(jnp.float32)), axis=-1) * vm
     out["k_pages"], out["k_scales"] = _quant_scatter(kp, ks, pidx, off,
-                                                     k_rows, k_amax)
+                                                     k_rows, k_amax, lead)
     out["v_pages"], out["v_scales"] = _quant_scatter(vp, vs, pidx, off,
-                                                     v_rows, v_amax)
+                                                     v_rows, v_amax, lead)
     return out
 
 

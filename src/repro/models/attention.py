@@ -415,7 +415,7 @@ def attention_prefill_paged(p: dict, x: jax.Array, a: AttentionConfig,
     max_pages = rest[0] if rest else None
     b, c, _ = x.shape
     kvh = a.kv_heads_effective()
-    kvh_store = cache["k_pages"].shape[1]
+    kvh_store = cache["k_pages"].shape[-3]
 
     apos = starts[:, None] + jnp.arange(c)[None, :]              # (B,c)
     q = linear_apply(p["wq"], x).reshape(b, c, a.heads_padded, a.head_dim)
@@ -449,7 +449,8 @@ def attention_prefill_paged(p: dict, x: jax.Array, a: AttentionConfig,
             rows = rows[:, :max_pages]
         o = paged_prefix_extend_attention(q, kp, vp, rows, starts,
                                           k_new, v_new, lengths, k_sc,
-                                          v_sc, use_kernel=use_kernel,
+                                          v_sc, layer=cache.get("layer"),
+                                          use_kernel=use_kernel,
                                           mesh=mesh, tp_impl=tp_impl)
     o = o.reshape(b, c, a.heads_padded * a.head_dim)
     y = linear_apply(p["wo"], _mask_pad_heads(o.astype(x.dtype), a))
@@ -495,7 +496,7 @@ def attention_verify_paged(p: dict, x: jax.Array, a: AttentionConfig,
     max_pages = rest[0] if rest else None
     b, w, _ = x.shape
     kvh = a.kv_heads_effective()
-    kvh_store = cache["k_pages"].shape[1]
+    kvh_store = cache["k_pages"].shape[-3]
 
     apos = lengths[:, None] + jnp.arange(w)[None, :]             # (S,W)
     q = linear_apply(p["wq"], x).reshape(b, w, a.heads_padded, a.head_dim)
@@ -516,7 +517,8 @@ def attention_verify_paged(p: dict, x: jax.Array, a: AttentionConfig,
     o = paged_prefix_extend_attention(q, kp, vp, bt, lengths,
                                       k_new.astype(jnp.bfloat16),
                                       v_new.astype(jnp.bfloat16), widths,
-                                      k_sc, v_sc, use_kernel=use_kernel,
+                                      k_sc, v_sc, layer=cache.get("layer"),
+                                      use_kernel=use_kernel,
                                       mesh=mesh, tp_impl=tp_impl)
     o = o.reshape(b, w, a.heads_padded * a.head_dim)
     y = linear_apply(p["wo"], _mask_pad_heads(o.astype(x.dtype), a))
@@ -594,7 +596,9 @@ def attention_decode_paged(p: dict, x: jax.Array, a: AttentionConfig,
 
     x: (S,1,d); pos: (S,) per-slot lengths — position where this token's
     K/V is written.  cache: {k_pages, v_pages[, k_scales, v_scales],
-    block_table} from ``repro.kvcache.alloc_paged``.  Slots without
+    block_table} from ``repro.kvcache.alloc_paged`` — inside the layer
+    loop the whole layer stack of pools plus ``layer``, which the token
+    write and the kernel address in place.  Slots without
     allocated pages write to the null page and read back zeros (their
     outputs are garbage; the engine masks them).  Quantized pools run
     the fused-dequant kernel variant (scales scalar-prefetched).
@@ -605,7 +609,7 @@ def attention_decode_paged(p: dict, x: jax.Array, a: AttentionConfig,
         raise NotImplementedError("paged decode: sliding window unsupported")
     b, _, d = x.shape
     kvh = a.kv_heads_effective()
-    kvh_store = cache["k_pages"].shape[1]
+    kvh_store = cache["k_pages"].shape[-3]
     pos = _posv(pos, b)
     posv = pos[:, None]
 
@@ -628,6 +632,7 @@ def attention_decode_paged(p: dict, x: jax.Array, a: AttentionConfig,
     with jax.named_scope("attn_kernel"):
         k_pages, v_pages, k_sc, v_sc, bt = kvcache.paged_views(cache)
         o = paged_attention(q, k_pages, v_pages, bt, pos + 1, k_sc, v_sc,
+                            layer=cache.get("layer"),
                             use_kernel=use_kernel, mesh=mesh,
                             tp_impl=tp_impl)                   # (S,H,D)
     o = o.reshape(b, 1, a.heads_padded * a.head_dim)

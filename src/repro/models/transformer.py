@@ -11,17 +11,27 @@ Block layout (pre-norm residual):
     [x = x + xattn(norm_x(x), src)]    (VLM / enc-dec blocks)
     x = x + mlp_or_moe(norm2(x))
 
+Paged pools are carried, not sliced: when a cache holds paged nodes
+(``k_pages``), the layer scan keeps their stacked pools (L,N,KH,page,D)
+and scales in its carry and hands each layer the whole stack plus its
+index (``"layer"``), so the token write scatters into the stack in
+place and the paged kernels read the layer's pages at that index; only
+the small per-layer leaves (block tables, verify's staging K/V) ride
+the scan's xs/ys.  Contiguous caches, SSM states and training
+(``cache=None``) are sliced per layer as xs/ys.
+
 Named regions (``jax.named_scope``) mark what a serving program spends
 its device time on; an op belongs to the innermost region on its path:
-``kv_pool`` — the layer loop's slicing of each layer's cache out of the
-stacked pool and its write back (the loop itself, around the layer
-body; the loop slices the layer's weights too, and XLA keeps a few of
-those slices as ops of their own); ``proj_mlp`` — a layer's norms,
-projections, residuals and MLP (the whole layer body, around the two
-below), plus the embedding and the head; ``kv_write`` — the cache write
-of a layer's new keys and values; ``attn_kernel`` — the attention
-computation over the cache; ``sample`` — sampling, in the engines'
-programs (``repro.serve``).
+``kv_pool`` — the layer loop itself, around the layer body: the slicing
+of a layer's weights and small cache leaves (XLA keeps a few of those
+slices as ops of their own) and, for caches it does not carry, the
+slicing of each layer's cache out of the stack and its write back;
+``proj_mlp`` — a layer's norms, projections, residuals and MLP (the
+whole layer body, around the two below), plus the embedding and the
+head; ``kv_write`` — the cache write of a layer's new keys and values
+(for a paged pool, a scatter of the new rows into the carried stack);
+``attn_kernel`` — the attention computation over the cache; ``sample``
+— sampling, in the engines' programs (``repro.serve``).
 """
 from __future__ import annotations
 
@@ -319,6 +329,79 @@ def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int,
             for i in range(g)}
 
 
+_POOL_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales")
+
+
+def _paged_paths(tree, path: tuple = ()) -> list:
+    """Key paths of the paged nodes (``{k_pages, ...}``) in a cache tree."""
+    if not isinstance(tree, dict):
+        return []
+    if "k_pages" in tree:
+        return [path]
+    return [p for k, v in tree.items() for p in _paged_paths(v, path + (k,))]
+
+
+def _get(tree, path: tuple):
+    """The node at ``path``, or None where the tree has none."""
+    for k in path:
+        if not isinstance(tree, dict) or k not in tree:
+            return None
+        tree = tree[k]
+    return tree
+
+
+def _put(tree, path: tuple, node):
+    """``tree`` with the node at ``path`` replaced by ``node``."""
+    if not path:
+        return node
+    return {**tree, path[0]: _put(tree[path[0]], path[1:], node)}
+
+
+def _without_pools(node: dict) -> dict:
+    return {k: v for k, v in node.items()
+            if k not in _POOL_KEYS and k != "layer"}
+
+
+def _scan_carrying_pools(body, x, params, cache, paths, n_groups, unroll):
+    """The layer scan over a cache with paged nodes at ``paths``: their
+    stacked pools ride the carry and every layer gets the whole stack plus
+    its index, so no layer's pool is sliced out or written back; the rest
+    of the cache (block tables, staging K/V) is sliced per layer as xs/ys.
+    A layer that hands back no paged node (spec verify reads the pools
+    and returns only its staging node) leaves the carried pools as they
+    were."""
+    pools = [{k: v for k, v in _get(cache, p).items() if k in _POOL_KEYS}
+             for p in paths]
+    rest = cache
+    for p in paths:
+        rest = _put(rest, p, _without_pools(_get(cache, p)))
+
+    def scan_body(carry, xs):
+        x, pools = carry
+        gp, c, layer = xs
+        for p, pool in zip(paths, pools):
+            c = _put(c, p, {**_get(c, p), **pool, "layer": layer})
+        y, nc, aux = body(x, gp, c)
+        new_pools = []
+        for p, pool in zip(paths, pools):
+            node = _get(nc, p)
+            if node is None or "k_pages" not in node:
+                new_pools.append(pool)
+                continue
+            new_pools.append({k: node[k] for k in pool})
+            nc = _put(nc, p, _without_pools(node))
+        return (y, new_pools), (nc, aux)
+
+    layers = jnp.arange(n_groups, dtype=jnp.int32)
+    (x, pools), (new_cache, auxs) = jax.lax.scan(
+        scan_body, (x, pools), (params, rest, layers), unroll=unroll)
+    for p, pool in zip(paths, pools):
+        node = _get(new_cache, p)
+        if node is not None:
+            new_cache = _put(new_cache, p, {**node, **pool})
+    return x, new_cache, auxs
+
+
 def stack_forward(params: dict, x: jax.Array, cfg: ModelConfig, *,
                   mode: str = "train", cache: Optional[dict] = None,
                   pos: Optional[jax.Array] = None,
@@ -339,6 +422,7 @@ def stack_forward(params: dict, x: jax.Array, cfg: ModelConfig, *,
             return y, (nc, aux)
 
         unroll = cfg.num_groups if cfg.scan_unroll else 1
+        paths = _paged_paths(cache)
         if cache is None:
             def scan_body_nocache(carry, gp):
                 y, _, aux = wrapped(carry, gp, None)
@@ -346,6 +430,10 @@ def stack_forward(params: dict, x: jax.Array, cfg: ModelConfig, *,
             x, auxs = jax.lax.scan(scan_body_nocache, x, params,
                                    unroll=unroll)
             new_cache = None
+        elif paths:
+            with jax.named_scope("kv_pool"):
+                x, new_cache, auxs = _scan_carrying_pools(
+                    wrapped, x, params, cache, paths, cfg.num_groups, unroll)
         else:
             with jax.named_scope("kv_pool"):
                 x, (new_cache, auxs) = jax.lax.scan(

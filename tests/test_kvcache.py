@@ -129,6 +129,176 @@ def test_quantized_paged_matches_bf16_oracle(dtype):
                                atol=tol, rtol=tol)
 
 
+# ---------------------------------------------------------------------------
+# stacked pools: inside the layer loop the writes and both paged kernels
+# take the whole (L, N, KH, page, D) stack plus the layer's index
+# (models/transformer.stack_forward)
+
+L_STACK = 3
+POOL_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales")
+
+
+def _stacked_pool(rng, dtype, n, kh, page, d):
+    """(L, N, KH, page, D) pool, different in every layer, and for int8
+    its (L, N, KH) scales."""
+    raw = rng.normal(size=(L_STACK, n, kh, page, d)).astype(np.float32)
+    if dtype == "bf16":
+        return jnp.asarray(raw, jnp.bfloat16), None
+    sc = np.abs(raw).max(axis=(3, 4)) / 127.0 + 1e-9
+    q = np.clip(np.round(raw / sc[..., None, None]), -127, 127)
+    return jnp.asarray(q, jnp.int8), jnp.asarray(sc, jnp.float32)
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "prefix_extend"])
+def test_stacked_pool_kernel_at_layer_matches_its_slice(kernel, dtype):
+    """Both paged kernels on a layer stack at layer l give exactly what
+    the 4-D call on ``pool[l]`` gives, for every layer, with partial last
+    pages and a free (length-0) slot."""
+    from repro.kernels.paged_attention.paged_attention import (
+        paged_attention_pallas, paged_prefix_extend_pallas)
+    rng = np.random.default_rng(3)
+    s_n, w_n, h, kh, d, page, p_n = 3, 4, 4, 2, 16, 8, 3
+    n = 1 + s_n * p_n
+    kp, ks = _stacked_pool(rng, dtype, n, kh, page, d)
+    vp, vs = _stacked_pool(rng, dtype, n, kh, page, d)
+    bt = jnp.asarray(rng.permutation(np.arange(1, n)).reshape(s_n, p_n),
+                     jnp.int32)
+    lengths = jnp.asarray([13, 0, 21], jnp.int32)
+    if kernel == "decode":
+        q = jnp.asarray(rng.normal(size=(s_n, h, d)), jnp.bfloat16)
+
+        def run(kp, vp, ks, vs, layer=None):
+            return paged_attention_pallas(q, kp, vp, bt, lengths, ks, vs,
+                                          layer, interpret=True)
+    else:
+        q = jnp.asarray(rng.normal(size=(s_n, w_n, h, d)), jnp.bfloat16)
+        ck = jnp.asarray(rng.normal(size=(s_n, w_n, kh, d)), jnp.bfloat16)
+        cv = jnp.asarray(rng.normal(size=(s_n, w_n, kh, d)), jnp.bfloat16)
+        widths = jnp.asarray([4, 0, 2], jnp.int32)
+
+        def run(kp, vp, ks, vs, layer=None):
+            return paged_prefix_extend_pallas(q, kp, vp, bt, lengths, ck, cv,
+                                              widths, ks, vs, layer,
+                                              interpret=True)
+
+    def pick(x, layer):
+        return None if x is None else x[layer]
+
+    outs = []
+    for layer in range(L_STACK):
+        got = np.asarray(run(kp, vp, ks, vs, jnp.int32(layer)), np.float32)
+        want = run(kp[layer], vp[layer], pick(ks, layer), pick(vs, layer))
+        np.testing.assert_array_equal(got, np.asarray(want, np.float32))
+        outs.append(got)
+    assert not np.array_equal(outs[0], outs[1])     # the index picks
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_stacked_writes_touch_only_their_layer(dtype):
+    """The token write (an int8 amax growth included) and the chunk
+    scatter on a stacked node at layer l equal the same writes on layer
+    l's own node, and leave every other layer as it was."""
+    rng = np.random.default_rng(4)
+    s, kh, d, page, pps = 3, 2, 16, 8, 3
+    a = AttentionConfig(kind="mha", num_heads=kh, num_kv_heads=kh,
+                        head_dim=d)
+    spec = CacheSpec(layout="paged", dtype=dtype, page_size=page)
+    bt = jnp.asarray(rng.permutation(np.arange(1, 1 + s * pps))
+                     .reshape(s, pps), jnp.int32)
+    slots = jnp.arange(s, dtype=jnp.int32)
+
+    def rows(t, amp=1.0):
+        return jnp.asarray(amp * rng.normal(size=(s, t, kh, d)),
+                           jnp.bfloat16)
+
+    nodes = []
+    for _ in range(L_STACK):
+        node = dict(alloc_paged(spec, a, s, 1 + s * pps, pps),
+                    block_table=bt)
+        nodes.append(paged_scatter_prefill(
+            node, slots, jnp.asarray([12, 5, 9], jnp.int32), rows(12),
+            rows(12)))
+    keys = [k for k in POOL_KEYS if k in nodes[0]]
+    stack = {k: jnp.stack([nd[k] for nd in nodes]) for k in keys}
+    layer = 1
+    k_tok, v_tok = rows(1, 4.0)[:, 0], rows(1, 4.0)[:, 0]
+    k_chunk, v_chunk = rows(8), rows(8)
+    writes = {
+        "token": lambda nd: paged_write_batch(
+            nd, jnp.asarray([12, 5, 9], jnp.int32), k_tok, v_tok),
+        "chunk": lambda nd: paged_scatter_prefill(
+            nd, slots, jnp.asarray([8, 3, 0], jnp.int32), k_chunk, v_chunk,
+            jnp.asarray([16, 8, 16], jnp.int32)),
+    }
+    for name, write in writes.items():
+        got = write({**stack, "block_table": bt, "layer": jnp.int32(layer)})
+        want = write(nodes[layer])
+        for k in keys:
+            for other in range(L_STACK):
+                expect = want[k] if other == layer else stack[k][other]
+                np.testing.assert_array_equal(
+                    np.asarray(got[k][other], np.float32),
+                    np.asarray(expect, np.float32), err_msg=f"{name} {k}")
+
+
+def _per_layer(tree, n):
+    """A layer-stacked tree as the unscanned stack's {"g<i>": ...}."""
+    return {f"g{i}": jax.tree.map(lambda x: x[i], tree) for i in range(n)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_layer_scan_carrying_pools_matches_layer_loop(dtype):
+    """The paged programs' layer scan, which carries the stacked pools
+    and hands each layer its index, gives the logits and pools of the
+    unscanned layer loop (one 4-D pool per layer): a chunk prefilled
+    into the pools, then two decode steps."""
+    from repro.configs import get_smoke_config
+    from repro.models.model import LM
+    from repro.serve.paged import set_block_table_rows
+    # float32 activations: in bf16 the scan and the unrolled loop round
+    # differently on the CPU, whatever the cache
+    cfg = get_smoke_config("qwen2-1.5b").with_(kv_cache_dtype=dtype,
+                                               dtype="float32")
+    lm, lm_loop = LM(cfg), LM(cfg.with_(scan_layers=False))
+    params = lm.init(jax.random.PRNGKey(0))
+    n_g = cfg.num_groups
+    params_loop = {**params, "layers": _per_layer(params["layers"], n_g)}
+    s, page, pps, c = 2, 8, 4, 12
+    cache = lm.init_paged_cache(s, 1 + s * pps, pps, page_size=page)
+    cache = set_block_table_rows(cache, [0, 1],
+                                 np.arange(1, 1 + s * pps).reshape(s, pps))
+    cache_loop = _per_layer(cache, n_g)
+    rng = np.random.default_rng(5)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (s, c)), jnp.int32)
+    slots = jnp.arange(s, dtype=jnp.int32)
+    starts = jnp.zeros((s,), jnp.int32)
+    lens = jnp.asarray([c, 7], jnp.int32)
+    lg, cache = lm.prefill_paged(params, toks, cache, slots, starts, lens)
+    lg_l, cache_loop = lm_loop.prefill_paged(params_loop, toks, cache_loop,
+                                             slots, starts, lens)
+    steps = [(lg, lg_l)]
+    pos = lens
+    for _ in range(2):
+        tok = jnp.argmax(lg, -1).astype(jnp.int32)
+        lg, cache = lm.decode_step(params, tok, cache, pos)
+        lg_l, cache_loop = lm_loop.decode_step(params_loop, tok, cache_loop,
+                                               pos)
+        steps.append((lg, lg_l))
+        pos = pos + 1
+    for got, want in steps:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+    # int8 scales may differ in the last float32 bit, as the activations
+    for k in POOL_KEYS:
+        if k in cache["blk0"]["kv"]:
+            for i in range(n_g):
+                np.testing.assert_allclose(
+                    np.asarray(cache["blk0"]["kv"][k][i], np.float32),
+                    np.asarray(cache_loop[f"g{i}"]["blk0"]["kv"][k],
+                               np.float32), rtol=1e-6, atol=0)
+
+
 def test_requant_growth_keeps_earlier_tokens():
     """Decode writes with growing amax requantize the page in place; the
     earlier tokens must survive within (a couple of) quantization steps
