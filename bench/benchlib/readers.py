@@ -5,6 +5,12 @@ A reader defines ``read(ctx) -> float | None``.  It returns None when
 the run gave it nothing to read (no kernel of its name in the trace, no
 request admitted in the window, ...); the harness then leaves the metric
 out of the result line.
+
+A metric named as another plus a dotted suffix, such as
+``sched.prefix_hit_share.code`` or ``ttft_p95_ms.code``, is read as
+that other one (:func:`resolve`).  A cell added later brings entries of
+its own under such names, with their own ``workloads`` (and, for an
+end-to-end metric, its own bound), and no file here changes.
 """
 from __future__ import annotations
 
@@ -68,8 +74,23 @@ def idle_share(ctx: Context):
     return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
 
 
+def resolve(name: str, known) -> Optional[str]:
+    """The longest of ``name`` and its dotted prefixes (``a.b.c``,
+    ``a.b``, ``a``) for which ``known`` holds; None if none does."""
+    parts = name.split(".")
+    for i in range(len(parts), 0, -1):
+        if known(".".join(parts[:i])):
+            return ".".join(parts[:i])
+    return None
+
+
 def load(name: str):
-    path = METRICS_DIR / f"{name}.py"
+    """The reader of metric ``name`` (see :func:`resolve`)."""
+    base = resolve(name, lambda n: (METRICS_DIR / f"{n}.py").is_file())
+    if base is None:
+        raise FileNotFoundError(f"no reader for metric {name!r} under "
+                                f"{METRICS_DIR}")
+    path = METRICS_DIR / f"{base}.py"
     spec = importlib.util.spec_from_file_location(
         f"bench_metric_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)

@@ -3,12 +3,14 @@
 The reference is the decoder written out in ``jax.numpy`` at float32
 under ``default_matmul_precision("highest")``: embedding, then per layer
 ``x += o(attn(rmsnorm(x)))`` and ``x += down(silu(gate(h)) * up(h))``
-with ``h = rmsnorm(x)``, rotary embeddings on q and k (halves rotated),
-grouped-query attention (query head ``i`` reads kv head ``i // (H /
-KH)``), causal softmax, a final rmsnorm and the head (the embedding's
-transpose when tied).  No cache, no kernel, no batching: one sequence at
-a time, padded at its end to one length so that one program serves
-every request.  It imports nothing of the program and takes only the
+with ``h = rmsnorm(x)``, rotary embeddings on q and k (halves rotated;
+position ``p`` turns pair ``i`` by ``p / factor * theta ** (-2i / hd)``,
+where ``factor`` is the file's linear ``rope_scaling`` factor and 1
+without one), grouped-query attention (query head ``i`` reads kv head
+``i // (H / KH)``), causal softmax, a final rmsnorm and the head (the
+embedding's transpose when tied).  No cache, no kernel, no batching: one
+sequence at a time, padded at its end to one length so that one program
+serves every request.  It imports nothing of the program and takes only the
 benchmark's weights, made anew from the seed.
 
 What is compared: for each served token of a sample of finished
@@ -33,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchlib.config_keys import rope_factor
 from benchlib.flops import Dims
 from benchlib.weights import LAYER_KEYS
 
@@ -45,10 +48,13 @@ def _rms(x, scale, eps):
         * scale
 
 
-def _rope(x, theta):
+def _rope(x, theta, factor):
     t, _, hd = x.shape
     freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs
+    pos = jnp.arange(t, dtype=jnp.float32)
+    if factor != 1.0:           # linear position interpolation
+        pos = pos / factor
+    ang = pos[:, None] * freqs
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
@@ -97,7 +103,7 @@ def _attend(q, k, v):
     return out.reshape(t, h * hd)
 
 
-def _hidden(w, tokens, m: Dims, eps, theta, mode):
+def _hidden(w, tokens, m: Dims, eps, theta, factor, mode):
     f32 = jnp.float32
     x = jnp.take(w["embed"], tokens, axis=0).astype(f32)
     layer_w = {k: w[k] for k in LAYER_KEYS if k in w}
@@ -109,8 +115,9 @@ def _hidden(w, tokens, m: Dims, eps, theta, mode):
         q = _mm(h, lw["wq"], mode) + lw.get("bq", 0.0)
         k = _mm(h, lw["wk"], mode) + lw.get("bk", 0.0)
         v = _mm(h, lw["wv"], mode) + lw.get("bv", 0.0)
-        q = _rope(_bf(q, mode).reshape(t, m.h, m.hd), theta)
-        k = _kv(_rope(_bf(k, mode).reshape(t, m.kh, m.hd), theta), mode)
+        q = _rope(_bf(q, mode).reshape(t, m.h, m.hd), theta, factor)
+        k = _kv(_rope(_bf(k, mode).reshape(t, m.kh, m.hd), theta, factor),
+                mode)
         v = _kv(_bf(v, mode).reshape(t, m.kh, m.hd), mode)
         o = _bf(_attend(q, k, v), mode)
         x = _bf(x + _mm(o, lw["wo"], mode), mode)
@@ -141,11 +148,11 @@ def _rows(x, fn):
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(m: Dims, eps: float, theta: float):
+def _programs(m: Dims, eps: float, theta: float, factor: float):
     def ref(w, tokens, targets):
         """Best logit at each position, and the logits of ``targets``
         (one row of tokens per reading: served, then each control's)."""
-        x = _hidden(w, tokens, m, eps, theta, "f32")
+        x = _hidden(w, tokens, m, eps, theta, factor, "f32")
         hw = _head_w(w).astype(jnp.float32)
 
         def fn(i, xb):
@@ -160,7 +167,7 @@ def _programs(m: Dims, eps: float, theta: float):
             targets.shape)
 
     def control_top(w, tokens, mode):
-        x = _hidden(w, tokens, m, eps, theta, mode)
+        x = _hidden(w, tokens, m, eps, theta, factor, mode)
         hw = _head_w(w).astype(jnp.float32)
         return _rows(x, lambda i, xb: jnp.argmax(
             _mm(_bf(xb, mode), hw, mode), -1).astype(jnp.int32)).reshape(-1)
@@ -175,7 +182,7 @@ def gaps(w: dict, m: Dims, cfg: dict, samples, length: int, *,
     for each mode in ``controls`` ("fp8"), the gaps of the
     tokens that the control ranks first at the same positions."""
     ref, ctrl_top = _programs(m, float(cfg["rms_norm_eps"]),
-                              float(cfg["rope_theta"]))
+                              float(cfg["rope_theta"]), rope_factor(cfg))
     pad = -(-length // Q_BLOCK) * Q_BLOCK
     names = ["served"] + list(controls)
     out = {k: [] for k in names}

@@ -3,7 +3,8 @@ configuration file.
 
 This is the one module of the benchmark that imports the program.  It
 maps the configuration's published keys onto the program's
-``ModelConfig``, lays the benchmark's weights out as the program keeps
+``ModelConfig`` (and refuses, by ``benchlib.config_keys``, the keys it
+cannot express), lays the benchmark's weights out as the program keeps
 them (and checks that layout against the program's own ``init``), and
 builds ``SchedEngine`` the way ``repro.launch.serve --policy fcfs``
 does: paged pools, the Pallas decode and prefix-extend kernels, chunked
@@ -17,22 +18,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchlib import config_keys
+
 
 def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file.  Raises
+    ``config_keys.Unmapped`` on a key that it cannot express."""
     from repro.configs.base import AttentionConfig, ModelConfig
+    config_keys.check(cfg)
     h = cfg["num_attention_heads"]
     s = cfg["serving"]
+    attn = dict(kind="gqa", num_heads=h,
+                num_kv_heads=cfg["num_key_value_heads"],
+                head_dim=cfg.get("head_dim") or cfg["hidden_size"] // h,
+                rope_theta=float(cfg["rope_theta"]),
+                qkv_bias=bool(cfg["attention_bias"]))
+    factor = config_keys.rope_factor(cfg)
+    if factor != 1.0:
+        attn["rope_scaling_factor"] = factor
+    try:
+        attention = AttentionConfig(**attn)
+    except TypeError as e:
+        if "rope_scaling_factor" not in attn:
+            raise
+        # a program with no field for the factor
+        raise config_keys.Unmapped("rope_scaling", cfg["rope_scaling"],
+                                   str(e)) from e
     return ModelConfig(
         name=cfg["name"], family="dense",
         num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
         d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        attention=AttentionConfig(
-            kind="gqa", num_heads=h,
-            num_kv_heads=cfg["num_key_value_heads"],
-            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // h,
-            rope_theta=float(cfg["rope_theta"]),
-            qkv_bias=bool(cfg["attention_bias"])),
+        attention=attention,
         tie_embeddings=bool(cfg["tie_word_embeddings"]),
         norm="rmsnorm", norm_eps=float(cfg["rms_norm_eps"]),
         dtype=s["dtype"], kv_cache_dtype=s["kv_dtype"],

@@ -10,7 +10,11 @@ out false.  The tests plant the same faults at smoke size.
 - ``token_altered``: greedy sampling hands back the token after the
   best one, where the token is produced;
 - ``state_unchanged``: every decode step hands back the cache it was
-  given, so the step's keys and values are never written.
+  given, so the step's keys and values are never written;
+- ``rope_unscaled``: the program is built from the file without its
+  ``rope_scaling``, so it serves unscaled positions where the file
+  scales them (a cell whose file has no ``rope_scaling`` cannot have
+  this fault).
 """
 import sys
 from pathlib import Path
@@ -18,7 +22,7 @@ from pathlib import Path
 import jax.numpy as jnp
 
 BENCH = Path(__file__).resolve().parents[1]
-FAULTS = ("token_altered", "state_unchanged")
+FAULTS = ("token_altered", "state_unchanged", "rope_unscaled")
 
 
 def _next_token(logits, temps, key):
@@ -41,6 +45,14 @@ def patches(fault: str) -> list:
             logits, _ = step(self, params, token, cache, pos)
             return logits, cache
         return [(LM, "decode_step", stale)]
+    if fault == "rope_unscaled":
+        from benchlib import system
+        mapping = system.program_config
+
+        def unscaled(cfg):
+            return mapping({k: v for k, v in cfg.items()
+                            if k != "rope_scaling"})
+        return [(system, "program_config", unscaled)]
     raise ValueError(f"unknown fault {fault!r}; one of {FAULTS}")
 
 

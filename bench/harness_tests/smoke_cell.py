@@ -1,5 +1,7 @@
 """A cell at a size the CPU runs in seconds: the qwen2-1.5b file with
-its widths and depth cut, and the cell's mix with lengths cut to fit."""
+its widths and depth cut, and the cell's mix with lengths cut to fit.
+The scaled smoke cell's file adds linear RoPE scaling by 4, as
+deepseek-coder-33b's does."""
 import json
 from pathlib import Path
 
@@ -7,8 +9,10 @@ BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
 
 
-def smoke_config() -> dict:
+def smoke_config(scaled: bool = False) -> dict:
     cfg = json.loads((BENCH / "configs" / "qwen2-1.5b.json").read_text())
+    if scaled:
+        cfg["rope_scaling"] = {"type": "linear", "factor": 4.0}
     cfg.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
                num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
                vocab_size=512)
@@ -43,9 +47,9 @@ def smoke_mix(name: str) -> dict:
     return mix
 
 
-def smoke_cell(traffic: str) -> dict:
+def smoke_cell(traffic: str, scaled: bool = False) -> dict:
     manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
     return {"cell": {"name": f"smoke.{traffic}", "chips": 1},
-            "config": smoke_config(), "mix": smoke_mix(traffic),
+            "config": smoke_config(scaled), "mix": smoke_mix(traffic),
             "end_to_end": manifest["end_to_end"],
             "per_layer": manifest["per_layer"]}

@@ -11,6 +11,9 @@ the traffic for ``--seconds`` and drains what fell due.  With
 ``--trace 1`` it records a stretch of the window with the profiler and
 reports the cell's per-layer metrics (one reader each, under
 ``bench/metrics``); with ``--trace 0`` it reports the end-to-end ones.
+A metric named as another plus a dotted suffix (``ttft_p95_ms.code``)
+is read as that one (``benchlib.readers.resolve``), so a cell added
+later brings metric entries of its own and no file here changes.
 
 After the window the engine is freed and a sample of the served
 requests is compared with the plain float32 reference
@@ -53,22 +56,25 @@ def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
-def load_cell(workload: str) -> dict:
-    """The cell's entry, configuration, mix and metric names."""
-    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+def load_cell(workload: str, root: Path = ROOT) -> dict:
+    """The cell's entry, configuration, mix and metrics, from the
+    ``BENCHMARK.json`` under ``root``: the metrics whose ``workloads``
+    name the cell, or that have no such list."""
+    manifest = json.loads((root / "BENCHMARK.json").read_text())
     cells = {c["name"]: c for c in manifest["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}")
     c = cells[workload]
+    bench = root / BENCH.relative_to(ROOT)
 
     def mine(m):
         return workload in m.get("workloads", [workload])
 
     return {
         "cell": c,
-        "config": json.loads((BENCH / "configs" /
+        "config": json.loads((bench / "configs" /
                               f"{c['config']}.json").read_text()),
-        "mix": json.loads((BENCH / "traffic" /
+        "mix": json.loads((bench / "traffic" /
                            f"{c['traffic']}.json").read_text()),
         "end_to_end": [m for m in manifest["end_to_end"] if mine(m)],
         "per_layer": [m for m in manifest["per_layer"] if mine(m)],
@@ -280,9 +286,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     else:
         e2e = cl.end_to_end(win)
         e2e["setup_s"] = hooks["setup_s"]
-        result["metrics"] = {m["name"]: {"value": e2e[m["name"]],
-                                         "unit": m["unit"]}
-                             for m in cell["end_to_end"] if m["name"] in e2e}
+        # a suffixed name reads its base quantity (readers.resolve)
+        for m in cell["end_to_end"]:
+            k = readers.resolve(m["name"], e2e.__contains__)
+            if k is not None:
+                result["metrics"][m["name"]] = {"value": e2e[k],
+                                                "unit": m["unit"]}
 
     # --- correct: the sample against the reference -----------------------
     chk = cfg["check"]
